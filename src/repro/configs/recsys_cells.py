@@ -20,7 +20,7 @@ from jax.sharding import PartitionSpec as P
 
 from repro.configs.base import Cell, sds
 from repro.core.roo_batch import ROOBatch
-from repro.distributed.sharding import ShardingPlan, shard_map
+from repro.distributed.sharding import ShardingPlan
 from repro.models.dlrm import (DLRMConfig, dlrm_flops_per_example,
                                dlrm_forward_roo, dlrm_init)
 from repro.models.din_dien import DIENConfig, dien_init, dien_logits_roo
@@ -277,7 +277,7 @@ def _sparse_row_update(table, acc, ids, g, *, plan, sharded: bool,
 
     t_spec = P_(m, None) if sharded else P_(None, None)
     a_spec = P_(m) if sharded else P_(None)
-    return shard_map(
+    return jax.shard_map(
         fn, mesh=plan.mesh,
         in_specs=(t_spec, a_spec, P_(ba), P_(ba, None)),
         out_specs=(t_spec, a_spec),

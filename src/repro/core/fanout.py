@@ -18,8 +18,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from repro.distributed.sharding import shard_map
-
 
 def fanout(x_ro: jnp.ndarray, segment_ids: jnp.ndarray) -> jnp.ndarray:
     """Broadcast request-level rows to impression slots.
@@ -72,5 +70,6 @@ def fanout_local(x_ro: jnp.ndarray, segment_ids: jnp.ndarray, mesh,
         valid = (seg < b_local)
         return out * valid.reshape((-1,) + (1,) * n_feat_axes).astype(out.dtype)
 
-    return shard_map(_shard_fn, mesh=mesh,
-                         in_specs=in_specs, out_specs=out_specs)(x_ro, segment_ids)
+    return jax.shard_map(_shard_fn, mesh=mesh,
+                         in_specs=in_specs,
+                         out_specs=out_specs)(x_ro, segment_ids)

@@ -202,14 +202,16 @@ def hstu_attention_prefix_chunked(q: jnp.ndarray, k: jnp.ndarray,
 
 def hstu_layer_apply(params: Dict, cfg: HSTUConfig, x: jnp.ndarray,
                      mask: Union[jnp.ndarray, MaskSpec],
-                     backend: Optional[str] = None) -> jnp.ndarray:
+                     backend: Optional[str] = None,
+                     plan=None) -> jnp.ndarray:
     """x: (B, S, d). Returns (B, S, d).
 
     ``mask``: a :class:`MaskSpec` (preferred — routed through
     kernels/dispatch.py so the mask is generated inside the selected
     backend) or a dense (B, S, S) / (S, S) bool array (legacy path, which
     materializes scores + bias in HBM).
-    ``backend`` overrides ``cfg.attn_backend`` for this call.
+    ``backend`` overrides ``cfg.attn_backend`` for this call; ``plan`` is
+    the training run's sharding plan (the Pallas kernels run per device).
     """
     b, s, d = x.shape
     h, dqk, dv = cfg.n_heads, cfg.d_qk, cfg.d_v
@@ -225,7 +227,7 @@ def hstu_layer_apply(params: Dict, cfg: HSTUConfig, x: jnp.ndarray,
         rab = params["rab"] if cfg.use_rab else None
         av = dispatch.hstu_attention(q, k, v, rab, mask,
                                      backend=backend or cfg.attn_backend,
-                                     max_rel_pos=cfg.max_rel_pos)
+                                     max_rel_pos=cfg.max_rel_pos, plan=plan)
     else:
         if mask.ndim == 2:
             mask = mask[None]
@@ -247,10 +249,10 @@ def hstu_layer_apply(params: Dict, cfg: HSTUConfig, x: jnp.ndarray,
 
 def hstu_apply(params: Dict, cfg: HSTUConfig, x: jnp.ndarray,
                mask: Union[jnp.ndarray, MaskSpec],
-               backend: Optional[str] = None) -> jnp.ndarray:
+               backend: Optional[str] = None, plan=None) -> jnp.ndarray:
     x = _ln(x, cfg.eps) * params["in_ln_scale"] + params["in_ln_bias"]
     for layer in params["layers"]:
-        x = hstu_layer_apply(layer, cfg, x, mask, backend=backend)
+        x = hstu_layer_apply(layer, cfg, x, mask, backend=backend, plan=plan)
     return x
 
 

@@ -65,16 +65,18 @@ def target_positions(batch: ROOBatch, m_targets: int) -> Tuple[jnp.ndarray, jnp.
 def encode_roo(params: Dict, cfg: ROOSequenceConfig,
                hist_emb: jnp.ndarray, hist_lengths: jnp.ndarray,
                target_emb_ro: jnp.ndarray, target_counts: jnp.ndarray,
-               backend: Optional[str] = None) -> jnp.ndarray:
+               backend: Optional[str] = None, plan=None) -> jnp.ndarray:
     """ROO path: one (n+m) sequence per request.
 
     hist_emb: (B_RO, n, d); target_emb_ro: (B_RO, m, d) — targets gathered
     to request-major layout. Returns (B_RO, m, d) encoded target outputs.
-    ``backend`` overrides the attention backend (kernels/dispatch.py).
+    ``backend`` overrides the attention backend (kernels/dispatch.py);
+    ``plan`` is the training run's sharding plan.
     """
     x = jnp.concatenate([hist_emb, target_emb_ro], axis=1)   # (B_RO, n+m, d)
     spec = roo_spec(hist_lengths, target_counts, cfg.n_hist)
-    y = hstu_apply(params["hstu"], cfg.hstu, x, spec, backend=backend)
+    y = hstu_apply(params["hstu"], cfg.hstu, x, spec, backend=backend,
+                   plan=plan)
     return y[:, cfg.n_hist:, :]
 
 

@@ -56,22 +56,6 @@ class ShardingPlan:
         return P()
 
 
-def shard_map(f, *, mesh, in_specs, out_specs, check_vma: bool = True):
-    """``jax.shard_map`` across jax versions.
-
-    jax < 0.5 ships shard_map as ``jax.experimental.shard_map`` with the
-    replication check named ``check_rep``; newer releases promote it to
-    ``jax.shard_map`` with ``check_vma``. All repo call sites go through
-    this wrapper with the new-style keyword.
-    """
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=check_vma)
-    from jax.experimental.shard_map import shard_map as _shard_map
-    return _shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                      check_rep=check_vma)
-
-
 def replicated_plan() -> ShardingPlan:
     """CPU/test plan: no mesh, all constraints are no-ops."""
     return ShardingPlan(mesh=None)

@@ -22,7 +22,6 @@ from jax.sharding import Mesh, PartitionSpec as P
 
 from repro.data.jagged import JaggedTensor
 from repro.distributed import comms
-from repro.distributed.sharding import shard_map
 from repro.embeddings.bag import bag_lookup, bag_lookup_dense
 # table configs live with the collection (the embedding entry point);
 # re-exported here because the sharding plan machinery predates it
@@ -96,7 +95,7 @@ def sharded_bag_lookup(table: jnp.ndarray, ids: jnp.ndarray,
         part = comms.wire_transform(part, mode, block)
         return jax.lax.psum(part, model_axis)
 
-    return shard_map(
+    return jax.shard_map(
         fn, mesh=mesh,
         in_specs=(P(model_axis, None), P(batch_axes, None), P(batch_axes)),
         out_specs=P(batch_axes, None))(table, ids, lengths)
@@ -134,7 +133,7 @@ def sharded_seq_lookup(table: jnp.ndarray, ids: jnp.ndarray, *, mesh: Mesh,
         emb = comms.wire_transform(emb, mode, block)
         return jax.lax.psum(emb, model_axis)
 
-    return shard_map(
+    return jax.shard_map(
         fn, mesh=mesh,
         in_specs=(P(model_axis, None), P(batch_axes, None)),
         out_specs=P(batch_axes, None, None))(table, ids)
@@ -176,13 +175,10 @@ def sharded_jagged_bag_lookup(table: jnp.ndarray, ids: JaggedTensor, *,
             out = out / jnp.maximum(lens, 1).astype(out.dtype)[:, None]
         return out
 
-    # check_vma off: the cumsum inside segment_ids() trips jax<0.5's scan
-    # replication checker even though inputs/outputs are replicated
-    return shard_map(
+    return jax.shard_map(
         fn, mesh=mesh,
         in_specs=(P(model_axis, None), P(None), P(None)),
-        out_specs=P(None, None), check_vma=False)(table, ids.values,
-                                                  ids.lengths)
+        out_specs=P(None, None))(table, ids.values, ids.lengths)
 
 
 # NOTE: the plan-routed lookups (plan_seq_lookup & friends) moved into
@@ -219,7 +215,7 @@ def sharded_bag_lookup_rs(table: jnp.ndarray, ids: jnp.ndarray,
         return jax.lax.psum_scatter(part, model_axis, scatter_dimension=1,
                                     tiled=True)
 
-    return shard_map(
+    return jax.shard_map(
         fn, mesh=mesh,
         in_specs=(P(model_axis, None), P(batch_axes, None), P(batch_axes)),
         out_specs=P(batch_axes, model_axis))(table, ids, lengths)

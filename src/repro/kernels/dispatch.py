@@ -99,24 +99,61 @@ def resolve_emb_backend(backend: Optional[str] = None) -> str:
     return EMB_KNOB.resolve(UNSET if backend is None else backend)
 
 
+def _per_shard(kernel, plan, q, k, v, rab, hist_lengths, target_counts):
+    """Run a Pallas attention kernel once per device under ``plan``'s mesh.
+
+    The compiler cannot partition a Mosaic kernel, so it runs inside
+    ``shard_map``: batch rows split over the plan's batch axes, heads over
+    the model axis when they divide it (else each model-axis device
+    repeats its batch shard's heads). The bias table's gradient is summed
+    across batch shards by the map's transpose.
+
+    ``check_vma`` is off because ``pallas_call`` declares its outputs with
+    plain shapes that name no mesh axes they vary over; the specs above
+    state that instead.
+    """
+    from jax.sharding import PartitionSpec as P
+    n_model = plan.mesh.shape[plan.model_axis]
+    m = plan.model_axis if q.shape[1] % n_model == 0 else None
+    ba = plan.batch_axes
+    qkv = P(ba, m)
+    args, specs = [q, k, v, hist_lengths, target_counts], [qkv] * 3 + [P(ba)] * 2
+    if rab is not None:
+        args.append(rab)
+        specs.append(P(m))
+    return jax.shard_map(
+        lambda q, k, v, hl, tc, rab=None: kernel(q, k, v, rab, hl, tc),
+        mesh=plan.mesh, in_specs=tuple(specs), out_specs=qkv,
+        check_vma=False)(*args)
+
+
 def hstu_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
                    rab: Optional[jnp.ndarray], spec: MaskSpec,
                    backend: Optional[str] = None, *,
                    max_rel_pos: int = 128,
-                   block_q: int = 128, block_k: int = 128) -> jnp.ndarray:
+                   block_q: int = 128, block_k: int = 128,
+                   plan=None) -> jnp.ndarray:
     """Masked HSTU pointwise attention on the selected backend.
 
     q, k: (B, H, S, Dqk); v: (B, H, S, Dv); rab: (H, 2*max_rel_pos+1) or
     None; ``spec`` describes the ROO mask structurally (never densified
     except on the jnp-dense oracle). All backends are differentiable and
-    agree within test tolerances (tests/test_dispatch.py).
+    agree within test tolerances (tests/test_dispatch.py). Under an enabled
+    sharding ``plan`` the Pallas kernels run per device (:func:`_per_shard`);
+    the jnp backends are left to the partitioner.
     """
     be = resolve_backend(backend)
     if be in ("pallas", "pallas-interpret"):
         from repro.kernels.hstu_attention import hstu_attention as _pallas
-        return _pallas(q, k, v, rab, spec.n_hist, spec.hist_lengths,
-                       spec.target_counts, max_rel_pos, block_q, block_k,
-                       interpret=(be == "pallas-interpret"))
+
+        def kernel(q, k, v, rab, hist_lengths, target_counts):
+            return _pallas(q, k, v, rab, spec.n_hist, hist_lengths,
+                           target_counts, max_rel_pos, block_q, block_k,
+                           interpret=(be == "pallas-interpret"))
+        if plan is not None and plan.enabled:
+            return _per_shard(kernel, plan, q, k, v, rab, spec.hist_lengths,
+                              spec.target_counts)
+        return kernel(q, k, v, rab, spec.hist_lengths, spec.target_counts)
     if be == "jnp-chunked":
         from repro.core.hstu import hstu_attention_chunked
         return hstu_attention_chunked(q, k, v, rab, spec,

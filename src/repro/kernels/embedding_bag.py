@@ -92,6 +92,9 @@ def _fwd_call(statics, table, safe_ids, lengths):
             _max_kernel, neg=float(jnp.finfo(table.dtype).min))
     else:
         kernel = _sum_kernel
+    # rows are viewed as (1, d) planes of 3-D arrays: a (1, d) block is
+    # then the full extent of the last two dims, which the TPU's (8, 128)
+    # tiling rule accepts for any d
     out = pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
@@ -99,13 +102,15 @@ def _fwd_call(statics, table, safe_ids, lengths):
             grid=(b, l),
             in_specs=[
                 # the scalar-prefetched id picks the table row block to DMA
-                pl.BlockSpec((1, d), lambda bi, li, ids, lens: (ids[bi, li], 0)),
+                pl.BlockSpec((1, 1, d),
+                             lambda bi, li, ids, lens: (ids[bi, li], 0, 0)),
             ],
-            out_specs=pl.BlockSpec((1, d), lambda bi, li, ids, lens: (bi, 0)),
+            out_specs=pl.BlockSpec((1, 1, d),
+                                   lambda bi, li, ids, lens: (bi, 0, 0)),
         ),
-        out_shape=jax.ShapeDtypeStruct((b, d), table.dtype),
+        out_shape=jax.ShapeDtypeStruct((b, 1, d), table.dtype),
         interpret=interpret,
-    )(safe_ids, lengths, table)
+    )(safe_ids, lengths, table.reshape(v, 1, d)).reshape(b, d)
     if pooling == "mean":
         out = out / jnp.maximum(lengths, 1).astype(out.dtype)[:, None]
     elif pooling == "max":
@@ -134,15 +139,16 @@ def _bwd_coo_rows(statics, table, safe_ids, lengths, out, g):
             num_scalar_prefetch=2,
             grid=(b, l),
             in_specs=[
-                pl.BlockSpec((1, d), lambda bi, li, ids, lens: (bi, 0)),
+                pl.BlockSpec((1, 1, d), lambda bi, li, ids, lens: (bi, 0, 0)),
             ],
             out_specs=pl.BlockSpec(
-                (1, d), lambda bi, li, ids, lens, _l=l: (bi * _l + li, 0)),
+                (1, 1, d),
+                lambda bi, li, ids, lens, _l=l: (bi * _l + li, 0, 0)),
         ),
-        out_shape=jax.ShapeDtypeStruct((b * l, d), table.dtype),
+        out_shape=jax.ShapeDtypeStruct((b * l, 1, d), table.dtype),
         interpret=interpret,
-    )(safe_ids, lengths, g)
-    return rows
+    )(safe_ids, lengths, g.reshape(b, 1, d))
+    return rows.reshape(b * l, d)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(0,))

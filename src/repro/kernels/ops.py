@@ -1,14 +1,15 @@
-"""jit'd public wrappers over the Pallas kernels with oracle fallback.
+"""jit'd public wrappers over the Pallas kernels.
 
-``use_pallas``: "always" (Pallas kernel — compiled on TPU, interpret mode
-elsewhere), "auto" (kernels/dispatch.py resolution: env/default knobs,
-else pallas on TPU and the chunked jnp path off-TPU), "never" (pure-jnp
-dense oracle — the default the distributed dry-run lowers, so SPMD
-partitioning sees plain XLA ops; kernels are validated separately).
+Every wrapper takes a ``backend`` and resolves it through
+:mod:`repro.kernels.dispatch` (explicit argument > scoped override >
+process default > env var > auto: compiled Pallas on TPU, the jnp path
+elsewhere). This module makes no choice of its own, so a kernel runs in
+interpret mode only when ``pallas-interpret`` was asked for.
 """
 from __future__ import annotations
 
 from functools import partial
+from typing import Optional
 
 import jax
 
@@ -19,39 +20,27 @@ from repro.kernels.dot_interaction import dot_interaction as _dot_pallas
 from repro.kernels.embedding_bag import embedding_bag as _bag_pallas
 
 
-def _on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
-
-
-@partial(jax.jit, static_argnames=("n_hist", "max_rel_pos", "use_pallas"))
+@partial(jax.jit, static_argnames=("n_hist", "max_rel_pos", "backend"))
 def hstu_attention(q, k, v, rab, hist_lengths, target_counts, *,
                    n_hist: int, max_rel_pos: int = 128,
-                   use_pallas: str = "never"):
+                   backend: Optional[str] = None):
     spec = MaskSpec(n_hist, hist_lengths, target_counts)
-    if use_pallas == "never":
-        backend = "jnp-dense"
-    elif use_pallas == "always":
-        backend = "pallas" if _on_tpu() else "pallas-interpret"
-    else:                      # "auto": env/default/hardware resolution
-        backend = None
     return _dispatch.hstu_attention(q, k, v, rab, spec, backend=backend,
                                     max_rel_pos=max_rel_pos)
 
 
-@partial(jax.jit, static_argnames=("use_pallas", "pooling"))
+@partial(jax.jit, static_argnames=("pooling", "backend"))
 def embedding_bag(table, ids, lengths, *, pooling: str = "sum",
-                  use_pallas: str = "never"):
-    if use_pallas == "never":
-        return _ref.embedding_bag_ref(table, ids, lengths, pooling)
-    if use_pallas == "always":
-        backend = "pallas" if _on_tpu() else "pallas-interpret"
-    else:                      # "auto": env/default/hardware resolution
-        backend = None
+                  backend: Optional[str] = None):
     return _bag_pallas(table, ids, lengths, pooling, backend=backend)
 
 
-@partial(jax.jit, static_argnames=("use_pallas",))
-def dot_interaction(dense_out, sparse_embs, *, use_pallas: str = "never"):
-    if use_pallas == "never":
+@partial(jax.jit, static_argnames=("backend",))
+def dot_interaction(dense_out, sparse_embs, *,
+                    backend: Optional[str] = None):
+    """DLRM dot interaction on the embedding-kernel backend family."""
+    be = _dispatch.resolve_emb_backend(backend)
+    if be == "jnp":
         return _ref.dot_interaction_ref(dense_out, sparse_embs)
-    return _dot_pallas(dense_out, sparse_embs, interpret=not _on_tpu())
+    return _dot_pallas(dense_out, sparse_embs,
+                       interpret=(be == "pallas-interpret"))

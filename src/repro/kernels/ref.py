@@ -1,7 +1,8 @@
 """Pure-jnp oracles for every Pallas kernel in this package.
 
 These are the ground truth the kernel tests assert_allclose against, and the
-implementations the models use on CPU (and whenever ``use_pallas=False``).
+implementations the models use on CPU (the ``jnp`` backends of
+kernels/dispatch.py).
 """
 from __future__ import annotations
 

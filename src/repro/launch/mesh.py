@@ -9,20 +9,31 @@ device state (the dry-run sets XLA_FLAGS before any jax init).
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
+
+
+def auto_mesh(shape, axes):
+    """``jax.make_mesh`` with ``Auto`` axes. JAX now defaults to
+    ``Explicit`` axes, under which every gather and sharding constraint
+    must spell out its output sharding; the repo's sharding rules are
+    written for the partitioner to propagate (GSPMD-style), so every mesh
+    is built here."""
+    return jax.make_mesh(tuple(shape), tuple(axes),
+                         axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return auto_mesh(shape, axes)
 
 
 def make_test_mesh(n_data: int = 2, n_model: int = 2, multi_pod: bool = False):
     """Small mesh for CPU tests (requires xla_force_host_platform_device_count
     set by the test itself before jax init)."""
     if multi_pod:
-        return jax.make_mesh((2, n_data, n_model), ("pod", "data", "model"))
-    return jax.make_mesh((n_data, n_model), ("data", "model"))
+        return auto_mesh((2, n_data, n_model), ("pod", "data", "model"))
+    return auto_mesh((n_data, n_model), ("data", "model"))
 
 
 def make_mesh_from_spec(spec: str):
@@ -46,7 +57,7 @@ def make_mesh_from_spec(spec: str):
         raise RuntimeError(
             f"mesh {spec} needs {need} devices but only {have} visible — "
             f"on CPU run with XLA_FORCE_HOST_PLATFORM_DEVICE_COUNT={need}")
-    return jax.make_mesh(dims, axes)
+    return auto_mesh(dims, axes)
 
 
 # TPU v5e roofline constants (per chip)

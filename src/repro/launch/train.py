@@ -260,6 +260,8 @@ def main(argv=None):
     args = _parser().parse_args(argv)
     if not args.arch and not args.config:
         raise SystemExit("pass --arch <id> or --config spec.json")
+    from repro.launch.compile_cache import enable_compilation_cache
+    enable_compilation_cache()
 
     # LM/GNN smoke paths predate the scenario surface and keep their
     # direct construction (they are not recsys scenarios)

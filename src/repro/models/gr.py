@@ -81,7 +81,8 @@ def gr_ranking_logits_from_history(params: Dict, cfg: GRConfig,
                             vocab=cfg.n_items, plan=plan)
     tgt_ro = gather_targets_to_ro(tgt_nro, batch, cfg.m_targets)
     enc = encode_roo({"hstu": params["hstu"]}, cfg.seq_cfg(), hist, lengths,
-                     tgt_ro, batch.num_impressions)          # (B_RO, m, d)
+                     tgt_ro, batch.num_impressions,
+                     plan=plan)                              # (B_RO, m, d)
     feats = scatter_targets_to_nro(enc, batch, cfg.m_targets)
     return mlp_apply(params["task_head"], feats)
 
@@ -211,7 +212,8 @@ def gr_retrieval_loss(params: Dict, cfg: GRConfig, batch: ROOBatch,
     hist = _embed_history(params, cfg, batch, plan=plan)
     lengths = jnp.minimum(batch.history_lengths, cfg.hist_len)
     spec = causal_spec(lengths, cfg.hist_len)
-    enc = hstu_apply(params["hstu"], cfg.hstu, hist, spec)   # (B_RO, n, d)
+    enc = hstu_apply(params["hstu"], cfg.hstu, hist, spec,
+                     plan=plan)                              # (B_RO, n, d)
     # position t predicts item t+1
     q = enc[:, :-1, :]
     nxt = batch.history_ids[:, 1:cfg.hist_len]

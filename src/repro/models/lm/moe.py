@@ -20,7 +20,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from repro.distributed.sharding import ShardingPlan, shard_map
+from repro.distributed.sharding import ShardingPlan
 
 
 @dataclasses.dataclass(frozen=True)
@@ -195,7 +195,7 @@ def moe_layer(x: jnp.ndarray, lyr: Dict, cfg: MoEConfig,
     # check_vma: the training path is fully checkable; the replicated-token
     # inference path is provably invariant (tokens replicated + psum over
     # model) but the static checker can't see through the FSDP all_gather.
-    return shard_map(
+    return jax.shard_map(
         fn, mesh=plan.mesh,
         in_specs=(x_spec, P(None, None),
                   P(m, fs, None), P(m, fs, None), P(m, None, fs)),

@@ -25,8 +25,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from repro.distributed.sharding import (ShardingPlan, replicated_plan,
-                                         shard_map)
+from repro.distributed.sharding import ShardingPlan, replicated_plan
 from repro.models.lm.moe import MoEConfig, moe_init, moe_layer, moe_param_specs
 
 
@@ -310,7 +309,7 @@ def _layer_spmd(x, lyr, cfg: LMConfig, plan: ShardingPlan, positions):
     mlp_names = ("w1", "w3", "w2") if cfg.activation == "swiglu" \
         else ("w1", "w2")
     mlp_specs = tuple(P(fs, m) if n != "w2" else P(m, fs) for n in mlp_names)
-    return shard_map(
+    return jax.shard_map(
         fn, mesh=plan.mesh,
         in_specs=(P(ba, m, None), P(ba, None),
                   P(None,), P(fs, m), P(fs, None), P(m, fs), P(None,))

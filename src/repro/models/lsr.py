@@ -123,7 +123,8 @@ def _user_side(params: Dict, cfg: LSRConfig, batch: ROOBatch,
                                  vocab=cfg.n_items, plan=plan)
         act = ec.seq_lookup(params["act_emb"], batch.history_actions, vocab=4)
         spec = causal_spec(batch.history_lengths, cfg.hist_len)
-        enc = hstu_apply(params["hstu"], _hstu_cfg(cfg), hist_emb + act, spec)
+        enc = hstu_apply(params["hstu"], _hstu_cfg(cfg), hist_emb + act, spec,
+                         plan=plan)
         valid = (jnp.arange(cfg.hist_len)[None] < batch.history_lengths[:, None])
         hist = jnp.sum(enc * valid[..., None], 1) / jnp.maximum(
             batch.history_lengths, 1).astype(enc.dtype)[:, None]

@@ -61,7 +61,10 @@ def build_stream_cfg(spec: ScenarioSpec):
         n_users=d.n_users, n_items=spec.stream_n_items(),
         n_requests=d.n_requests, product=d.product,
         hist_init_max=d.hist_init_max, seed=d.seed,
-        late_fraction=d.late_fraction)
+        late_fraction=d.late_fraction,
+        # a window longer than the stream's default cap must be fillable
+        hist_len_max=max(EventStreamConfig.hist_len_max,
+                         spec.batcher.hist_len))
 
 
 def build_batcher_cfg(spec: ScenarioSpec, n_shards: int = 1):

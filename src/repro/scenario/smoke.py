@@ -86,6 +86,8 @@ def main(argv=None) -> None:
                          "the span trace as Chrome trace-event JSON")
     args = ap.parse_args(argv)
 
+    from repro.launch.compile_cache import enable_compilation_cache
+    enable_compilation_cache()
     from repro.configs.registry import SCENARIO_ARCHS, scenario
     from repro.obs.log import get_logger
     log = get_logger("scenario-smoke")

@@ -13,7 +13,7 @@ import pytest
 import jax
 import jax.numpy as jnp
 
-from _hypothesis_compat import given, settings, st
+from hypothesis import given, settings, strategies as st
 
 from repro.distributed import comms
 from repro.embeddings.sparse import SparseRows

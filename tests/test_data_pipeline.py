@@ -4,7 +4,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from _hypothesis_compat import given, settings, st
+from hypothesis import given, settings, strategies as st
 
 from repro.data.jagged import JaggedTensor
 from repro.embeddings.bag import bag_lookup, bag_lookup_dense
@@ -178,7 +178,8 @@ os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
 import jax, jax.numpy as jnp, numpy as np
 from repro.embeddings.sharded import sharded_bag_lookup
 from repro.embeddings.bag import bag_lookup_dense
-mesh = jax.make_mesh((2, 2), ("data", "model"))
+from repro.launch.mesh import auto_mesh
+mesh = auto_mesh((2, 2), ("data", "model"))
 rng = jax.random.PRNGKey(0)
 table = jax.random.normal(rng, (64, 8))
 ids = jax.random.randint(rng, (8, 5), 0, 64)
@@ -197,6 +198,7 @@ np.testing.assert_allclose(np.asarray(g1), np.asarray(g2), atol=1e-4)
 print("SHARDED_OK")
 '''
         env = dict(os.environ)
+        env["JAX_PLATFORMS"] = "cpu"     # the child must never claim a chip
         env["PYTHONPATH"] = os.path.join(os.path.dirname(__file__), "..", "src")
         r = subprocess.run([sys.executable, "-c", code], capture_output=True,
                            text=True, env=env, timeout=300)

@@ -199,7 +199,8 @@ class TestMicrobatchSPMD:
         mb = jax.tree.map(lambda a, b: jnp.stack([a, b]),
                           dist_batches[0], dist_batches[1])
         placed = spmd.place_batch(mb, plan, batch_dim=1)
-        assert tuple(placed.ro_dense.sharding.spec) == (None, ("data",), None)
+        assert placed.ro_dense.sharding.spec == jax.sharding.PartitionSpec(
+            None, ("data",), None)
         rng = jax.random.PRNGKey(3)
 
         def run(plan_, batch):
@@ -515,7 +516,7 @@ class TestPrefetchSharding:
             sharding=spmd.make_batch_sharding_fn(plan))
         batch, _ = next(iter(loader.batches()))
         ro = batch.ro_dense
-        assert tuple(ro.sharding.spec)[0] == ("data",)
+        assert ro.sharding.spec[0] in ("data", ("data",))
         # two distinct row blocks, not 8 replicas
         assert _distinct_shard_blocks(ro) == 2
         # and the sharded forward consumes it directly

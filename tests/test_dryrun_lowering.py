@@ -16,7 +16,8 @@ from repro.configs.registry import get_arch
 from repro.distributed.sharding import plan_for_mesh
 from repro.launch.hlo_analysis import analyze
 
-mesh = jax.make_mesh((2, 4), ("data", "model"))
+from repro.launch.mesh import auto_mesh
+mesh = auto_mesh((2, 4), ("data", "model"))
 plan = plan_for_mesh(mesh)
 out = {}
 cells = [("dlrm-mlperf", "train_batch"), ("mind", "serve_p99"),
@@ -30,10 +31,7 @@ for arch, shape in cells:
             cell.abstract_state(), cell.input_specs()).compile()
     a = analyze(c.as_text())
     m = c.memory_analysis()
-    peak = getattr(m, "peak_memory_in_bytes", None)
-    if peak is None:  # older jax: no peak stat; sum the live buffer classes
-        peak = (m.temp_size_in_bytes + m.argument_size_in_bytes
-                + m.output_size_in_bytes)
+    peak = m.peak_memory_in_bytes
     out[f"{arch}/{shape}"] = {
         "flops": a["flops"], "coll": a["collective_bytes"],
         "mem": a["memory_bytes"], "peak": peak}
@@ -44,6 +42,7 @@ print("RESULT=" + json.dumps(out))
 @pytest.fixture(scope="module")
 def lowered():
     env = dict(os.environ)
+    env["JAX_PLATFORMS"] = "cpu"     # the child must never claim a chip
     env["PYTHONPATH"] = os.path.join(os.path.dirname(__file__), "..", "src")
     r = subprocess.run([sys.executable, "-c", _CODE], capture_output=True,
                        text=True, env=env, timeout=900)

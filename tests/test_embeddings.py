@@ -10,7 +10,7 @@ rtol 1e-5 over 50 steps (grad summation order differs between the paths).
 import jax
 import jax.numpy as jnp
 import numpy as np
-from _hypothesis_compat import given, settings, st
+from hypothesis import given, settings, strategies as st
 
 from repro.data.jagged import JaggedTensor
 from repro.embeddings import collection as ec
@@ -228,7 +228,12 @@ class TestSparseGradAccum:
 
         losses_d, state_d = run(None)
         losses_s, state_s = run(vag)
-        np.testing.assert_allclose(losses_s, losses_d, rtol=1e-6)
+        # The two paths add the microbatches' row contributions in different
+        # orders (dense accumulator vs COO segment-sum): the first step's
+        # table differs by 1 ulp at the table's scale, and 8 Adam/Adagrad
+        # steps carry that into the loss (up to 10 ulps measured). Bound:
+        # 32 ulps of f32 (32 * 2**-23 ~= 3.8e-6).
+        np.testing.assert_allclose(losses_s, losses_d, rtol=4e-6)
         np.testing.assert_allclose(np.asarray(state_s["params"]["emb"]),
                                    np.asarray(state_d["params"]["emb"]),
                                    rtol=1e-5, atol=1e-7)

@@ -1,7 +1,6 @@
 """Validate the loop-aware HLO analyzer against unrolled references."""
 import jax
 import jax.numpy as jnp
-import numpy as np
 import pytest
 
 from repro.launch.hlo_analysis import analyze
@@ -32,10 +31,7 @@ class TestHLOAnalysis:
         expect = n_layers * 2 * 128 * 256 * 256
         assert a["flops"] == pytest.approx(expect, rel=0.01)
         # XLA's own analysis counts the body once — the bug we correct
-        # (cost_analysis returns a per-device list on older jax)
         ca = c.cost_analysis()
-        if isinstance(ca, (list, tuple)):
-            ca = ca[0]
         assert ca["flops"] < expect / (n_layers / 1.5)
 
     def test_scan_equals_unrolled(self):

@@ -508,5 +508,11 @@ class TestEngineFromScenarioIncremental:
         again = engine.score_requests(requests)    # repeat: all prefixes hit
         assert engine.state_store.stats.hits > 0
         assert engine.stats.n_incremental_batches > 0
+        # The repeat pass attends from the target rows alone against the
+        # cached K/V: the same sums, but in matmuls of another row count.
+        # XLA's CPU dot picks its reduction blocking from the shape, so the
+        # history sum is reassociated (a few ulps per output; the cold pass,
+        # which has the full shape, is bit-equal to the stateless engine).
+        # Bound: 16 ulps of f32 at the logits' O(1) scale.
         for a, b in zip(scores, again):
-            np.testing.assert_array_equal(a, b)
+            np.testing.assert_allclose(a, b, rtol=2e-6, atol=2e-6)

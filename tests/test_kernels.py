@@ -4,7 +4,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from _hypothesis_compat import given, settings, st
+from hypothesis import given, settings, strategies as st
 
 from repro.kernels import ref
 from repro.kernels.dot_interaction import dot_interaction
@@ -157,6 +157,22 @@ class TestOpsWrappers:
         tbl = jax.random.normal(rng, (64, 16))
         ids = jax.random.randint(rng, (8, 4), 0, 64)
         lens = jnp.full((8,), 4, jnp.int32)
-        a = ops.embedding_bag(tbl, ids, lens, use_pallas="never")
-        b = ops.embedding_bag(tbl, ids, lens, use_pallas="auto")
+        a = ops.embedding_bag(tbl, ids, lens, backend="jnp")
+        b = ops.embedding_bag(tbl, ids, lens, backend="pallas-interpret")
         np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=1e-6)
+
+    def test_wrappers_resolve_through_dispatch(self, monkeypatch):
+        """No wrapper picks interpret mode on its own: off a TPU the auto
+        rung is the jnp path, and ``pallas-interpret`` must be asked for."""
+        from repro.kernels import dispatch, ops
+        monkeypatch.delenv(dispatch.EMB_ENV_VAR, raising=False)
+        de = jnp.ones((8, 16))
+        sp = jnp.ones((8, 3, 16))
+        want = ref.dot_interaction_ref(de, sp)
+        assert dispatch.resolve_emb_backend() == "jnp"
+        np.testing.assert_array_equal(
+            np.asarray(ops.dot_interaction(de, sp)), np.asarray(want))
+        np.testing.assert_allclose(
+            np.asarray(ops.dot_interaction(de, sp,
+                                           backend="pallas-interpret")),
+            np.asarray(want), atol=1e-4)

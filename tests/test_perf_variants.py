@@ -63,7 +63,8 @@ os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
 import jax, jax.numpy as jnp, numpy as np
 from repro.configs.recsys_cells import _sparse_row_update
 from repro.distributed.sharding import plan_for_mesh, replicated_plan
-mesh = jax.make_mesh((2, 2), ("data", "model"))
+from repro.launch.mesh import auto_mesh
+mesh = auto_mesh((2, 2), ("data", "model"))
 plan = plan_for_mesh(mesh)
 rng = jax.random.PRNGKey(0)
 v, d, b = 64, 8, 16
@@ -82,6 +83,7 @@ np.testing.assert_allclose(np.asarray(a1), np.asarray(a2), atol=1e-6)
 print("SPARSE_EXCHANGE_OK")
 '''
         env = dict(os.environ)
+        env["JAX_PLATFORMS"] = "cpu"     # the child must never claim a chip
         env["PYTHONPATH"] = os.path.join(os.path.dirname(__file__), "..",
                                          "src")
         r = subprocess.run([sys.executable, "-c", code], capture_output=True,
@@ -99,7 +101,8 @@ os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
 import dataclasses, jax, jax.numpy as jnp, numpy as np
 from repro.models.lm.transformer import LMConfig, lm_init, lm_forward
 from repro.distributed.sharding import plan_for_mesh
-mesh = jax.make_mesh((2, 2), ("data", "model"))
+from repro.launch.mesh import auto_mesh
+mesh = auto_mesh((2, 2), ("data", "model"))
 plan = plan_for_mesh(mesh)
 cfg = LMConfig(name="t", n_layers=2, d_model=32, n_heads=4, n_kv_heads=2,
                d_head=8, d_ff=64, vocab=128, compute_dtype="float32")
@@ -113,6 +116,7 @@ np.testing.assert_allclose(np.asarray(h1), np.asarray(h2), atol=2e-4)
 print("SPMD_LAYER_OK")
 '''
         env = dict(os.environ)
+        env["JAX_PLATFORMS"] = "cpu"     # the child must never claim a chip
         env["PYTHONPATH"] = os.path.join(os.path.dirname(__file__), "..",
                                          "src")
         r = subprocess.run([sys.executable, "-c", code], capture_output=True,

@@ -7,7 +7,7 @@ import os
 
 import numpy as np
 import pytest
-from _hypothesis_compat import given, settings, st
+from hypothesis import given, settings, strategies as st
 
 from repro.configs.registry import SCENARIO_ARCHS, all_scenarios, scenario
 from repro.kernels import dispatch
@@ -54,7 +54,8 @@ class TestRoundTrip:
            seed=st.integers(min_value=0, max_value=2**31 - 1),
            late=st.floats(min_value=0.0, max_value=1.0,
                           allow_nan=False, width=32),
-           lr=st.floats(min_value=1e-6, max_value=1.0,
+           # bounds of a width-32 strategy must be float32 values
+           lr=st.floats(min_value=float(np.float32(1e-6)), max_value=1.0,
                         allow_nan=False, width=32),
            prefetch=st.booleans())
     def test_roundtrip_is_identity_under_overrides(

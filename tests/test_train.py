@@ -5,7 +5,7 @@ import os
 import jax
 import jax.numpy as jnp
 import numpy as np
-from _hypothesis_compat import given, settings, st
+from hypothesis import given, settings, strategies as st
 
 from repro.train.checkpoint import CheckpointManager
 from repro.train.compression import (compressed_bytes, ef_compress_grads,
