@@ -12,6 +12,9 @@ contract directly:
   obs_record_metrics — the same block with obs=metrics (locked record).
   obs_span_trace     — a 1k-span block under obs=trace (span open/close,
                        event append + duration histogram).
+  obs_span_profiled  — a 1k-span block with an engine flush's args, obs
+                       off, while a ``jax.profiler`` session records: the
+                       profiler bridge (one ``TraceAnnotation`` a span).
   obs_emit           — one TelemetryEmitter.emit() of a populated
                        registry snapshot to a JSONL line on disk.
 
@@ -69,6 +72,26 @@ def _span_block() -> float:
     return us
 
 
+def _profiled_span_block(tmp: str) -> float:
+    import jax
+
+    from repro.obs import trace
+    args = dict(requests=3, impressions=12, b_ro=4, b_nro=32,
+                history_events=4800, queued_ms_sum=12.5, queued_ms_max=5.1)
+
+    def block():
+        span = trace.span
+        for _ in range(SPAN_OPS):
+            with span("obs_bench.flush", **args):
+                pass
+
+    jax.profiler.start_trace(os.path.join(tmp, "profile"))
+    try:
+        return _best(block)
+    finally:
+        jax.profiler.stop_trace()
+
+
 def _emit_once(tmp: str) -> float:
     from repro.obs import export, metrics
     with metrics.OBS_KNOB.scoped("metrics"):
@@ -99,6 +122,9 @@ def run(smoke: bool = False) -> None:
     try:
         emit_us = _emit_once(tmp)
         emit("obs_emit", emit_us, f"series=128;us_per_line={emit_us:.0f}")
+        prof_us = _profiled_span_block(tmp)
+        emit("obs_span_profiled", prof_us,
+             f"spans={SPAN_OPS};us_per_span={prof_us / SPAN_OPS:.2f}")
     finally:
         import shutil
         shutil.rmtree(tmp, ignore_errors=True)

@@ -54,4 +54,5 @@ def dot_interaction(dense_out: jnp.ndarray, sparse_embs: jnp.ndarray,
         ),
         out_shape=jax.ShapeDtypeStruct((b, n_out), dense_out.dtype),
         interpret=interpret,
+        name="dot_interaction",
     )(jnp.asarray(tril), t)

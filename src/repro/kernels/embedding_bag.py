@@ -110,6 +110,7 @@ def _fwd_call(statics, table, safe_ids, lengths):
         ),
         out_shape=jax.ShapeDtypeStruct((b, 1, d), table.dtype),
         interpret=interpret,
+        name="embedding_bag_fwd",
     )(safe_ids, lengths, table.reshape(v, 1, d)).reshape(b, d)
     if pooling == "mean":
         out = out / jnp.maximum(lengths, 1).astype(out.dtype)[:, None]
@@ -147,6 +148,7 @@ def _bwd_coo_rows(statics, table, safe_ids, lengths, out, g):
         ),
         out_shape=jax.ShapeDtypeStruct((b * l, 1, d), table.dtype),
         interpret=interpret,
+        name="embedding_bag_bwd_rows",
     )(safe_ids, lengths, g.reshape(b, 1, d))
     return rows.reshape(b * l, d)
 

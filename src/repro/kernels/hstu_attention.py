@@ -337,6 +337,7 @@ def _fwd_call(statics, hist_lengths, target_counts, q, k, v, rab_row):
         ),
         out_shape=jax.ShapeDtypeStruct((b * h, s, dv), v.dtype),
         interpret=interpret,
+        name="hstu_attn_fwd",
     )(hist_lengths, target_counts, qf, kf, vf, rab_row)
     return out.reshape(b, h, s, dv)
 
@@ -374,6 +375,7 @@ def _bwd_call(statics, hist_lengths, target_counts, q, k, v, rab_row, g):
             jax.ShapeDtypeStruct((b * h, 1, n_row), jnp.float32),
         ],
         interpret=interpret,
+        name="hstu_attn_bwd_dq",
     )(hist_lengths, target_counts, qf, kf, vf, rab_row, dof)
 
     in_specs_k_inner = [  # grid (bh, ki, qi)
@@ -399,6 +401,7 @@ def _bwd_call(statics, hist_lengths, target_counts, q, k, v, rab_row, g):
             jax.ShapeDtypeStruct((b * h, s, dv), v.dtype),
         ],
         interpret=interpret,
+        name="hstu_attn_bwd_dkv",
     )(hist_lengths, target_counts, qf, kf, vf, rab_row, dof)
 
     dq = dq_f.reshape(b, h, s, dqk)
@@ -594,6 +597,7 @@ def hstu_attention_prefix(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
         ),
         out_shape=jax.ShapeDtypeStruct((b * h, r_pad, dv), v.dtype),
         interpret=interpret,
+        name="hstu_attn_prefix_fwd",
     )(prefix_lengths.astype(jnp.int32), new_counts.astype(jnp.int32),
       target_counts.astype(jnp.int32), qf, kf, vf, rab_row)
     out = out.reshape(b, h, r_pad, dv)

@@ -11,10 +11,11 @@ measurement substrate they all report into:
     and histograms (labeled series, fixed bucket ladders, lock-cheap
     record path) plus *collectors* that mirror every existing ``*Stats``
     object, so one :func:`snapshot` sees the whole stack;
-  * :mod:`repro.obs.trace` — context-manager/decorator spans on monotonic
-    clocks with per-request trace IDs, exported as Chrome trace-event
-    JSON (loadable in Perfetto / chrome://tracing), with an optional
-    ``jax.profiler`` hook for device traces;
+  * :mod:`repro.obs.trace` — context-manager/decorator spans with
+    per-request trace IDs, on the profiler's clock (``repro.<name>`` host
+    events next to the device timeline) whenever a ``jax.profiler``
+    session records, and exported as Chrome trace-event JSON (loadable
+    in Perfetto / chrome://tracing) when the mode is ``trace``;
   * :mod:`repro.obs.export` — periodic JSONL telemetry snapshots stamped
     with the scenario ``content_hash``; ``python -m repro.obs.report``
     summarizes a run file into per-phase rates/p50/p99;

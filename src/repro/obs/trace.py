@@ -1,13 +1,27 @@
-"""Span tracing on monotonic clocks, exported as Chrome trace-event JSON.
+"""Span tracing on the profiler's clock and in a Chrome trace-event buffer.
 
 A *span* is one timed phase — ``with span("engine.score", trace_id=7):``
-or ``@traced("train.step")`` — recorded as a Chrome *complete* event
-(``ph: "X"``) with microsecond ``ts``/``dur`` from ``perf_counter_ns``.
-Spans on the same thread nest by time containment, which is exactly how
-Perfetto / chrome://tracing renders call trees, so the engine's
-``engine.flush > engine.bucket / engine.score`` and the trainer's
-``train.step > train.data / train.compute`` show up as nested bars with
-no parent-pointer bookkeeping on the record path.
+or ``@traced("train.step")``. Spans on the same thread nest by time
+containment, which is how Perfetto, chrome://tracing and the JAX profiler
+render call trees, so the engine's ``engine.flush > engine.bucket /
+engine.score`` and the trainer's ``train.step > train.data /
+train.compute`` show up as nested bars with no parent-pointer bookkeeping
+on the record path.
+
+Each span goes to up to two places:
+
+  * **the profiler** — while a ``jax.profiler`` session records, every
+    span also opens a ``jax.profiler.TraceAnnotation("repro." + name,
+    **args)``, whatever the obs mode. It lands on the host plane of the
+    profiler's trace, on the clock of the device's ops, with its args as
+    the event's stats; ``_Span.set`` forwards to ``set_metadata``. Whoever
+    starts the profiler gets the program's phases next to the device
+    timeline.
+  * **the Chrome buffer** — when the obs mode is ``trace`` (knob ladder,
+    see ``repro.obs.metrics``), a Chrome *complete* event (``ph: "X"``,
+    microsecond ``ts``/``dur`` from ``perf_counter_ns``) and the metrics
+    histogram ``span.<name>`` (milliseconds), which is what
+    ``repro.obs.report`` derives per-phase rates/p50/p99 from.
 
 Trace IDs: the engine stamps every admitted request with an id from
 :func:`new_trace_id` and threads it through the span ``args`` of every
@@ -15,22 +29,17 @@ phase that touches the request (admission -> bucket -> score ->
 reassembly), so a p99 request found in the trace can be followed across
 batches — including requests split over several batches.
 
-Every closed span also feeds the metrics histogram ``span.<name>``
-(milliseconds), which is what ``repro.obs.report`` derives per-phase
-rates/p50/p99 from without re-parsing trace JSON.
-
-Cost: when the obs mode is not ``trace`` (knob ladder, see
-``repro.obs.metrics``), :func:`span` returns a shared no-op context
-manager — one knob resolve, no allocation. The event buffer is bounded
-(``max_events``); overflow drops new events and counts them in the
-``trace.dropped_events`` counter instead of growing without bound.
-
-``device_trace`` optionally brackets a region with ``jax.profiler``
-start/stop so XLA device timelines land next to the host spans.
+Cost: with the mode not ``trace`` and no profiler session recording,
+:func:`span` returns a shared no-op context manager — one knob resolve
+and one ``TraceAnnotation.is_enabled()`` check, no allocation.
+:func:`instant` is Chrome-only: the profiler has no instants, so what an
+instant records that the profiler should see rides a span's args. The
+event buffer is bounded (``max_events``); overflow drops new events and
+counts them in the ``trace.dropped_events`` counter instead of growing
+without bound.
 """
 from __future__ import annotations
 
-import contextlib
 import functools
 import itertools
 import json
@@ -39,11 +48,16 @@ import threading
 import time
 from typing import Any, Dict, List, Optional
 
+from jax.profiler import TraceAnnotation
+
 from repro.obs import metrics
 
 # process-unique, thread-safe request/trace id source (itertools.count is
 # atomic under the GIL)
 _TRACE_IDS = itertools.count(1)
+
+# every span's name on the profiler's trace starts with this
+PROFILER_PREFIX = "repro."
 
 
 def new_trace_id() -> int:
@@ -72,28 +86,44 @@ _NULL_SPAN = _NullSpan()
 
 
 class _Span:
-    __slots__ = ("_tracer", "name", "cat", "args", "_t0")
+    """One open phase: a profiler annotation while a session records
+    (``profiled``), a Chrome event when the mode is ``trace`` (``tracer``
+    set), or both."""
+    __slots__ = ("_tracer", "name", "cat", "args", "_profiled",
+                 "_annotation", "_t0")
 
-    def __init__(self, tracer: "Tracer", name: str, cat: str,
-                 args: Dict[str, Any]):
+    def __init__(self, tracer: Optional["Tracer"], name: str, cat: str,
+                 args: Dict[str, Any], profiled: bool):
         self._tracer = tracer
         self.name = name
         self.cat = cat
         self.args = args
+        self._profiled = profiled
+        self._annotation = None
 
     def set(self, **args) -> None:
         """Attach/overwrite args while the span is open."""
         self.args.update(args)
+        if self._annotation is not None:
+            self._annotation.set_metadata(**args)
 
     def __enter__(self) -> "_Span":
-        self._t0 = time.perf_counter_ns()
+        # a TraceAnnotation starts timing when it is built
+        if self._profiled:
+            self._annotation = TraceAnnotation(PROFILER_PREFIX + self.name,
+                                               **self.args)
+        if self._tracer is not None:
+            self._t0 = time.perf_counter_ns()
         return self
 
     def __exit__(self, *exc) -> bool:
-        dur_ns = time.perf_counter_ns() - self._t0
-        self._tracer._record_complete(self.name, self.cat, self._t0,
-                                      dur_ns, self.args)
-        metrics.histogram("span." + self.name).observe(dur_ns / 1e6)
+        if self._tracer is not None:
+            dur_ns = time.perf_counter_ns() - self._t0
+            self._tracer._record_complete(self.name, self.cat, self._t0,
+                                          dur_ns, self.args)
+            metrics.histogram("span." + self.name).observe(dur_ns / 1e6)
+        if self._annotation is not None:
+            self._annotation.__exit__(*exc)
         return False
 
 
@@ -108,13 +138,18 @@ class Tracer:
 
     # -- recording --------------------------------------------------------------
     def span(self, name: str, cat: str = "repro", **args):
-        """Context manager timing one phase; no-op unless mode=trace."""
-        if not tracing_enabled():
+        """Context manager timing one phase: a profiler event while a
+        profiler session records, a Chrome event when mode=trace, the
+        shared no-op when neither."""
+        record = tracing_enabled()
+        profiled = TraceAnnotation.is_enabled()
+        if not (record or profiled):
             return _NULL_SPAN
-        return _Span(self, name, cat, args)
+        return _Span(self if record else None, name, cat, args, profiled)
 
     def instant(self, name: str, cat: str = "repro", **args) -> None:
-        """Zero-duration marker (e.g. per-request admission)."""
+        """Zero-duration marker (e.g. per-request admission); Chrome
+        buffer only, for the profiler has no instants."""
         if not tracing_enabled():
             return
         self._push({"name": name, "cat": cat, "ph": "i", "s": "t",
@@ -187,29 +222,3 @@ def traced(name: Optional[str] = None, cat: str = "repro"):
                 return fn(*a, **kw)
         return wrapper
     return deco
-
-
-@contextlib.contextmanager
-def device_trace(logdir: Optional[str]):
-    """Bracket a region with ``jax.profiler`` start/stop when available.
-
-    ``logdir=None`` (or an unavailable/already-active profiler) degrades
-    to a no-op — host-side spans keep working either way.
-    """
-    started = False
-    if logdir:
-        try:
-            import jax
-            jax.profiler.start_trace(logdir)
-            started = True
-        except Exception:
-            started = False
-    try:
-        yield
-    finally:
-        if started:
-            try:
-                import jax
-                jax.profiler.stop_trace()
-            except Exception:
-                pass

@@ -278,12 +278,12 @@ class ScoringEngine:
         """Admit one request; returns a ticket redeemable via ``take``."""
         ticket = self._next_ticket
         self._next_ticket += 1
+        now = self.clock()
         if not self._pending:
-            self._oldest_ts = self.clock()
+            self._oldest_ts = now
         self._pending.append((ticket, request))
         self._pending_imps += request.num_impressions
-        if obs_metrics.metrics_enabled():
-            self._submit_ts[ticket] = self.clock()
+        self._submit_ts[ticket] = now
         return ticket
 
     def poll(self, now: Optional[float] = None) -> bool:
@@ -315,10 +315,11 @@ class ScoringEngine:
     def _drain(self) -> None:
         pending, self._pending = self._pending, []
         self._pending_imps, self._oldest_ts = 0, None
-        for ticket, scores in self._score_keyed(pending):
+        observe = obs_metrics.metrics_enabled()
+        for ticket, scores in self._score_keyed(pending, self._submit_ts):
             self._results[ticket] = scores
-            t0 = self._submit_ts.pop(ticket, None)
-            if t0 is not None:
+            t0 = self._submit_ts.pop(ticket)
+            if observe:
                 obs_metrics.histogram("engine.request_ms").observe(
                     (self.clock() - t0) * 1e3)
 
@@ -339,10 +340,13 @@ class ScoringEngine:
         return out
 
     # ---- scoring core --------------------------------------------------------
-    def _score_keyed(self, keyed: Iterable[Tuple[Hashable, ROOSample]]
+    def _score_keyed(self, keyed: Iterable[Tuple[Hashable, ROOSample]],
+                     submit_ts: Optional[Dict[Hashable, float]] = None
                      ) -> Iterator[Tuple[Hashable, np.ndarray]]:
         """Split oversize requests, group into bucket-shaped flushes, score,
-        reassemble per original key. Yields each key exactly once."""
+        reassemble per original key. Yields each key exactly once.
+        ``submit_ts`` (online path) maps each key to its ``submit`` time,
+        for the flush span's queueing args."""
         top = self.ladder.max_rung
         tracing = obs_trace.tracing_enabled()
         trace_ids: Dict[Hashable, int] = {}
@@ -406,33 +410,51 @@ class ScoringEngine:
                 if group and (len(group) + 1 > top.b_ro
                               or group_imps + n > top.b_nro):
                     yield from reassemble(
-                        self._score_group(group, trace_ids))
+                        self._score_group(group, trace_ids, submit_ts))
                     yield from flush_empty()
                     group, group_imps = [], 0
                 group.append((key, part))
                 group_imps += n
         if group:
-            yield from reassemble(self._score_group(group, trace_ids))
+            yield from reassemble(
+                self._score_group(group, trace_ids, submit_ts))
         yield from flush_empty()
         assert not parts_needed, "engine bug: unreassembled request parts"
 
     def _score_group(self, group: List[Tuple[Hashable, ROOSample]],
-                     trace_ids: Dict[Hashable, int]
+                     trace_ids: Dict[Hashable, int],
+                     submit_ts: Optional[Dict[Hashable, float]] = None
                      ) -> Iterator[Tuple[Hashable, np.ndarray]]:
         """Score one flush-group at its bucket shape; yields (key, piece)
-        for every request part via the batch plan's slot mapping."""
+        for every request part via the batch plan's slot mapping.
+
+        Spans: ``engine.flush`` (args below) > ``engine.bucket`` (packing)
+        and, per batch, ``engine.score`` > ``engine.dispatch`` (the jitted
+        call) and ``engine.fetch`` (the wait for the scores and their copy
+        to the host). The flush's args: ``requests``, ``impressions``, the
+        rung's ``b_ro`` and ``b_nro``, ``history_events`` (each row's
+        history up to ``hist_len``), ``history_slots`` (``b_ro x hist_len``
+        over its batches) and, on the online path, ``queued_ms_sum`` /
+        ``queued_ms_max`` (the flush's start less each ``submit``)."""
+        hist_len = self.policy.hist_len
         n_imps = sum(s.num_impressions for _, s in group)
-        with obs_trace.span("engine.flush", requests=len(group),
-                            impressions=n_imps):
-            with obs_trace.span("engine.bucket") as bspan:
-                bucket = self.ladder.select(len(group), n_imps)
-                bspan.set(b_ro=bucket.b_ro, b_nro=bucket.b_nro)
+        bucket = self.ladder.select(len(group), n_imps)
+        args = dict(requests=len(group), impressions=n_imps,
+                    b_ro=bucket.b_ro, b_nro=bucket.b_nro,
+                    history_events=sum(min(len(s.history_ids), hist_len)
+                                       for _, s in group))
+        if submit_ts is not None:
+            start = self.clock()
+            queued = [(start - submit_ts[k]) * 1e3 for k, _ in group]
+            args.update(queued_ms_sum=sum(queued), queued_ms_max=max(queued))
+        with obs_trace.span("engine.flush", **args) as fspan:
+            with obs_trace.span("engine.bucket"):
                 self.stats.record_bucket(bucket)
                 batcher = ROOBatcher(BatcherConfig(
-                    b_ro=bucket.b_ro, b_nro=bucket.b_nro,
-                    hist_len=self.policy.hist_len))
+                    b_ro=bucket.b_ro, b_nro=bucket.b_nro, hist_len=hist_len))
                 samples = [s for _, s in group]
                 plans = list(batcher.batches_with_plan(samples))
+            fspan.set(history_slots=len(plans) * bucket.b_ro * hist_len)
             for batch, plan in plans:
                 if self._breaker_sheds():
                     for p in plan.requests:
@@ -492,9 +514,10 @@ class ScoringEngine:
                      plan: BatchPlan) -> np.ndarray:
         faults.maybe_fail("engine.score")   # injected forward failure
         from repro.kernels.dispatch import use_backend
-        with use_backend(self.attn_backend):
+        with use_backend(self.attn_backend), obs_trace.span("engine.dispatch"):
             scores = self._score_batch_device(batch, samples, plan)
-        out = np.asarray(scores)
+        with obs_trace.span("engine.fetch"):
+            out = np.asarray(scores)
         self._score_tail = out.shape[1:]
         return out
 

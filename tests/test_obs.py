@@ -199,6 +199,58 @@ class TestTrace:
                    for e in obs_trace.get_tracer().events())
 
 
+def _profiled(logdir, fn):
+    """Run ``fn`` under a ``jax.profiler`` session; the ``repro.*`` host
+    events of its trace as (name, start_ns, end_ns, thread, stats)."""
+    import glob
+
+    import jax
+    from jax.profiler import ProfileData
+    jax.profiler.start_trace(str(logdir))
+    try:
+        fn()
+    finally:
+        jax.profiler.stop_trace()
+    path, = glob.glob(f"{logdir}/**/*.xplane.pb", recursive=True)
+    return [(e.name, e.start_ns, e.start_ns + e.duration_ns, line.name,
+             dict(e.stats))
+            for plane in ProfileData.from_file(path).planes
+            if plane.name == "/host:CPU"
+            for line in plane.lines for e in line.events
+            if e.name.startswith(obs_trace.PROFILER_PREFIX)]
+
+
+class TestProfilerBridge:
+    def test_span_reaches_the_profiler_in_mode_off(self, tmp_path):
+        assert obs_metrics.mode() == "off"
+
+        def body():
+            with obs_trace.span("phase.y", rows=3) as s:
+                s.set(b_ro=4, note="x")
+            obs_trace.instant("marker")
+        events = _profiled(tmp_path, body)
+        assert [(n, st) for n, *_, st in events] == [
+            ("repro.phase.y", {"rows": 3, "b_ro": 4, "note": "x"})]
+        # mode off: nothing enters the Chrome buffer or the histograms
+        assert obs_trace.get_tracer().events() == []
+        assert "span.phase.y" not in obs_metrics.snapshot()[
+            "metrics"]["histograms"]
+
+    def test_mode_trace_records_both(self, trace_on, tmp_path):
+        def body():
+            with obs_trace.span("phase.z", k=1):
+                pass
+        events = _profiled(tmp_path, body)
+        assert [(n, st) for n, *_, st in events] == [
+            ("repro.phase.z", {"k": 1})]
+        chrome = obs_trace.get_tracer().events()
+        assert [(e["name"], e["args"]) for e in chrome] == [
+            ("phase.z", {"k": 1})]
+
+    def test_no_session_no_mode_builds_nothing(self):
+        assert obs_trace.span("a") is obs_trace.span("b")
+
+
 def _mk_request(uid, item_ids):
     from repro.core.joiner import ROOSample
     return ROOSample(
@@ -244,6 +296,67 @@ class TestEngineTracePropagation:
         score = by_name["engine.score"][0]
         assert flush["ts"] <= score["ts"]
         assert score["ts"] + score["dur"] <= flush["ts"] + flush["dur"]
+
+    def test_online_flush_spans_under_the_profiler(self, tmp_path):
+        """submit/poll under the profiler: the flush args agree with
+        ``EngineStats``, queueing is measured from each submit on the
+        engine's clock, and each fetch nests in its flush."""
+        from collections import Counter
+
+        from repro.serve.engine import EnginePolicy, ScoringEngine
+        now = [0.0]
+        engine = ScoringEngine(
+            None, lambda p, b: b.item_ids.astype(jnp.float32),
+            policy=EnginePolicy(max_requests=4, max_impressions=16,
+                                max_delay_ms=5.0),
+            clock=lambda: now[0])
+        reqs = [_mk_request(i, list(range(1, 2 + i % 3))) for i in range(6)]
+
+        def body():
+            tickets = []
+            for i, r in enumerate(reqs):
+                now[0] = i * 1e-3
+                tickets.append(engine.submit(r))
+            now[0] = 0.010
+            assert engine.poll()
+            assert all(engine.take(t) is not None for t in tickets)
+        events = _profiled(tmp_path, body)
+        flushes = [e for e in events if e[0] == "repro.engine.flush"]
+        fetches = [e for e in events if e[0] == "repro.engine.fetch"]
+        stats = engine.stats.snapshot()
+        args = [e[4] for e in flushes]
+        assert sum(a["requests"] for a in args) == stats["n_requests"] == 6
+        assert sum(a["impressions"] for a in args) == stats["n_impressions"]
+        assert dict(Counter(f"{a['b_ro']}x{a['b_nro']}" for a in args)) \
+            == stats["buckets"]["counts"]
+        assert len(fetches) == stats["n_batches"] == len(flushes) == 2
+        for name, s, e, line, _ in fetches:
+            assert any(fs <= s and e <= fe and fl == line
+                       for _, fs, fe, fl, _ in flushes)
+        # every submit read the clock: 10 ms less each submit time
+        queued = [10.0 - i for i in range(6)]
+        assert sum(a["queued_ms_sum"] for a in args) \
+            == pytest.approx(sum(queued))
+        assert max(a["queued_ms_max"] for a in args) == pytest.approx(10.0)
+        assert all(a["queued_ms_sum"] >= 0 for a in args)
+        # three history events a row, hist_len 64 slots a row
+        assert [a["history_events"] for a in args] == [
+            3 * a["requests"] for a in args]
+        assert [a["history_slots"] for a in args] == [
+            a["b_ro"] * 64 for a in args]
+
+    def test_bulk_flush_carries_no_queueing(self, tmp_path):
+        from repro.serve.engine import EnginePolicy, ScoringEngine
+        engine = ScoringEngine(
+            None, lambda p, b: b.item_ids.astype(jnp.float32),
+            policy=EnginePolicy(max_requests=4, max_impressions=16))
+        reqs = [_mk_request(i, [1, 2]) for i in range(3)]
+        events = _profiled(tmp_path, lambda: engine.score_requests(reqs))
+        flush, = [e[4] for e in events if e[0] == "repro.engine.flush"]
+        assert flush["requests"] == 3 and "queued_ms_sum" not in flush
+        names = {e[0] for e in events}
+        assert {"repro.engine.bucket", "repro.engine.score",
+                "repro.engine.dispatch", "repro.engine.fetch"} <= names
 
     def test_one_snapshot_sees_the_whole_stack(self, metrics_on):
         # the tentpole contract: serving + pipeline + training +

@@ -13,6 +13,7 @@ one process at a time may load the TPU library, and every test worker
 imports this file.
 """
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -68,6 +69,12 @@ def _hstu(q, k, v, rab, hl, tc):
                                       interpret=False)
 
 
+def _hstu_fwd_bwd(q, k, v, rab, hl, tc):
+    return jax.value_and_grad(
+        lambda q, k, v, rab: _hstu(q, k, v, rab, hl, tc).sum(),
+        argnums=(0, 1, 2, 3))(q, k, v, rab)
+
+
 def test_hstu_forward(one_chip):
     _compile(_hstu, *_hstu_args(one_chip))
 
@@ -75,12 +82,19 @@ def test_hstu_forward(one_chip):
 @pytest.mark.parametrize("precision", ["default", "highest"])
 def test_hstu_forward_backward(one_chip, precision):
     """Also under ``highest``, where chip_smoke.py compares trajectories."""
-    def fwd_bwd(q, k, v, rab, hl, tc):
-        return jax.value_and_grad(
-            lambda q, k, v, rab: _hstu(q, k, v, rab, hl, tc).sum(),
-            argnums=(0, 1, 2, 3))(q, k, v, rab)
     with jax.default_matmul_precision(precision):
-        _compile(fwd_bwd, *_hstu_args(one_chip))
+        _compile(_hstu_fwd_bwd, *_hstu_args(one_chip))
+
+
+def test_kernel_names_reach_the_compiled_hlo(one_chip):
+    """``pallas_call(name=...)`` names the kernel's HLO instruction, which
+    is the event name on a profiler trace's ``XLA Ops`` line; under
+    ``grad`` JAX's transform names wrap it (``transpose_jvp_<name>__.1``)."""
+    args = _hstu_args(one_chip)
+    assert "%hstu_attn_fwd." in _compile(_hstu, *args).as_text()
+    text = _compile(_hstu_fwd_bwd, *args).as_text()
+    for name in ("hstu_attn_fwd", "hstu_attn_bwd_dq", "hstu_attn_bwd_dkv"):
+        assert re.search(rf"%\w*{name}_*\.\d+ = ", text), name
 
 
 @pytest.mark.parametrize("n_new", [1, 1024])
