@@ -12,8 +12,22 @@ structural mask (history causal | target->history | target diagonal) plus
 per-request validity lengths are generated *inside* the kernel from block
 indices + scalar-prefetched lengths — the (S,S) mask never exists in HBM.
 
-Forward grid: (B*H, S/bq, S/bk), k innermost; output block revisited over k
-and accumulated in place.
+Block sparsity: only tiles in which the ROO mask can hold a true cell are
+visited. A static table lists the tiles the mask's structure allows (the
+history lower triangle of tiles, plus every target row block against the
+history and the target diagonal: 2,145 of the 4,225 tiles at S 8,320 in
+128 blocks); it is scalar-prefetched after the per-request lengths, and each
+kernel's grid is (B*H, n_tiles). Per request, ``_tile_live`` tests each
+tile's corners against ``hist_lengths`` / ``target_counts``, on the device
+before the kernel, into one scalar-prefetched word per request and step
+(``_step_info``): a dead step skips the body (``pl.when``) and the index
+maps of its streamed operands point at the block of the live step before
+it, so the pipeline copies nothing for it. Accumulator inits stay
+unconditional, so rows no live tile reaches come out as zeros. Sums are
+bit-for-bit those of the dense grid: a dead tile added exactly 0.
+
+Forward: the table row-major, so each output block's tiles are visited
+consecutively and it is accumulated in place.
 
 Relative-position bias: a tile's bias depends only on ``row - col``, so it
 is a Toeplitz matrix. The wrapper lays the compact (H, 2*max_rel+1) table
@@ -25,15 +39,15 @@ the (bq, bk) tile. The TPU has no vector gather, so this replaces a
 per-element ``take``.
 
 Backward recomputes scores blockwise (no O(S²) residuals) in two passes:
-  * dq + drab : grid (B*H, S/bq, S/bk), k innermost — dq accumulates over
-    k blocks; the bias gradient is the transpose of the expansion above:
+  * dq + drab : the row-major table — dq accumulates over a row's tiles;
+    the bias gradient is the transpose of the expansion above:
     the tile's rows are reversed (an exact permutation matmul), a strided
     rotation then lines each diagonal of dS up in one lane, a sublane sum
     gives the per-delta sums, and a dynamic rotation adds them into a
-    per-(b,h) row in the same descending-delta layout, revisited across the
-    whole (q, k) sub-grid. XLA folds that row back onto the compact table;
-  * dk + dv   : grid (B*H, S/bk, S/bq), q innermost — both accumulate over
-    q blocks.
+    per-(b,h) row in the same descending-delta layout, revisited across all
+    of the (b,h)'s tiles. XLA folds that row back onto the compact table;
+  * dk + dv   : the column-major table — both accumulate over a column's
+    tiles.
 
 Sequence lengths that do not divide the block size are handled by the
 wrapper with pad-and-crop: padded positions read as out-of-range targets,
@@ -42,6 +56,9 @@ pinned to the *unpadded* length so numerics are invariant to padding.
 """
 from __future__ import annotations
 
+import contextlib
+import contextvars
+import dataclasses
 import functools
 import math
 from typing import Optional
@@ -152,8 +169,88 @@ def _roo_mask(rows, cols, hl, tc, n_hist: int):
     return struct & valid_r & valid_c
 
 
+def _tile_live(qi, ki, hl, tc, *, n_hist: int, bq: int, bk: int, xp=jnp):
+    """True exactly when :func:`_roo_mask` holds a true cell in tile
+    ``(qi, ki)`` (rows ``[qi*bq, qi*bq + bq)``, columns ``[ki*bk, ki*bk +
+    bk)``) for a request of ``hl`` history events and ``tc`` targets.
+
+    The mask's true cells are the union of three parts, each a test on the
+    tile's corners: history rows and columns under ``min(hl, n_hist)`` with
+    the column range starting at or below the last row (causal); target
+    rows in ``[n_hist, n_hist + tc)`` against those history columns; the
+    target diagonal. Arguments broadcast: the wrappers evaluate it on the
+    device for every request and step of the table (``xp=jnp``), the host
+    on numpy arrays (``xp=np``): one definition for both."""
+    r0, c0 = qi * bq, ki * bk
+    r1, c1 = r0 + (bq - 1), c0 + (bk - 1)
+    hh = xp.minimum(xp.maximum(hl, 0), n_hist)     # valid history: [0, hh)
+    te = n_hist + xp.maximum(tc, 0)                # valid targets: [n_hist, te)
+    hist = (r0 < hh) & (c0 < hh) & (c0 <= r1)
+    tgt = (r1 >= n_hist) & (xp.maximum(r0, n_hist) < te) & (c0 < hh)
+    diag = (xp.maximum(xp.maximum(r0, c0), n_hist)
+            < xp.minimum(xp.minimum(r1, c1) + 1, te))
+    return hist | tgt | diag
+
+
+@functools.lru_cache(maxsize=None)
+def _tile_table(n_hist: int, s_pad: int, bq: int, bk: int, by_col: bool):
+    """The tiles the mask's structure allows at all: those live for a full
+    history and every padded slot a target. Row-major ``(qi, ki)`` pairs,
+    or column-major with ``by_col``, as two int32 arrays."""
+    qi, ki = np.meshgrid(np.arange(s_pad // bq), np.arange(s_pad // bk),
+                         indexing="ij")
+    if by_col:
+        qi, ki = qi.T, ki.T
+    qi, ki = qi.ravel(), ki.ravel()
+    keep = _tile_live(qi, ki, n_hist, s_pad - n_hist, n_hist=n_hist, bq=bq,
+                      bk=bk, xp=np)
+    return qi[keep].astype(np.int32), ki[keep].astype(np.int32)
+
+
+@dataclasses.dataclass(frozen=True)
+class TileGrid:
+    """Static geometry of one fused-kernel call, per (request, head): what
+    the host needs to count the tiles the kernels compute."""
+    n_heads: int
+    n_hist: int
+    s_pad: int
+    bq: int
+    bk: int
+
+    @property
+    def dense_tiles(self) -> int:
+        """Tiles of the dense ``(S/bq, S/bk)`` grid."""
+        return (self.s_pad // self.bq) * (self.s_pad // self.bk)
+
+    def live_tiles(self, hist_lengths, target_counts) -> np.ndarray:
+        """Live tiles of each request, per head: (B,) int."""
+        qi, ki = _tile_table(self.n_hist, self.s_pad, self.bq, self.bk,
+                             False)
+        hl = np.asarray(hist_lengths, np.int32)[:, None]
+        tc = np.asarray(target_counts, np.int32)[:, None]
+        return _tile_live(qi, ki, hl, tc, n_hist=self.n_hist, bq=self.bq,
+                          bk=self.bk, xp=np).sum(axis=1)
+
+
+_TRACED_GRIDS: contextvars.ContextVar = contextvars.ContextVar(
+    "hstu_traced_grids", default=None)
+
+
+@contextlib.contextmanager
+def record_tile_grids():
+    """Collect the :class:`TileGrid` of every fused HSTU call traced inside
+    (one per attention layer): the geometry is static, so a caller records
+    it while jit traces and counts tiles on the host afterwards."""
+    grids: list = []
+    token = _TRACED_GRIDS.set(grids)
+    try:
+        yield grids
+    finally:
+        _TRACED_GRIDS.reset(token)
+
+
 def _block_scores_and_mask(len_ref, cnt_ref, q, k, rab_ref, *,
-                           b: int, qi, ki, n_hist: int,
+                           b, qi, ki, n_hist: int,
                            bq: int, bk: int, max_rel: int, use_rab: bool):
     """Recompute the pre-activation scores (incl. bias) and the ROO mask for
     one (bq, bk) tile. q, k are f32 (bq, dqk)/(bk, dqk)."""
@@ -176,118 +273,119 @@ def _silu_grad(x):
     return s * (1.0 + x * (1.0 - s))
 
 
-def _fwd_kernel(len_ref, cnt_ref,            # scalar prefetch: (B,), (B,)
+def _step(qi_ref, ki_ref, info_ref, *, n_heads: int, n_steps: int):
+    """This grid step's batch row, tile, whether the tile is live, and
+    whether it opens a run of tiles sharing its output block (see
+    :func:`_step_info`)."""
+    t = pl.program_id(1)
+    b = pl.program_id(0) // n_heads
+    info = info_ref[b * n_steps + t]
+    return b, qi_ref[t], ki_ref[t], (info & 1) == 1, (info & 2) == 2
+
+
+def _fwd_kernel(len_ref, cnt_ref, qi_ref, ki_ref, info_ref,  # prefetch
                 q_ref, k_ref, v_ref, rab_ref,
                 o_ref, *, n_hist: int, scale_len: int, n_heads: int,
-                bq: int, bk: int, max_rel: int, use_rab: bool):
-    bh = pl.program_id(0)
-    qi = pl.program_id(1)
-    ki = pl.program_id(2)
-    b = bh // n_heads
+                n_steps: int, bq: int, bk: int, max_rel: int, use_rab: bool):
+    b, qi, ki, live, first = _step(qi_ref, ki_ref, info_ref,
+                                   n_heads=n_heads, n_steps=n_steps)
 
-    q = q_ref[0].astype(jnp.float32)                     # (bq, dqk)
-    k = k_ref[0].astype(jnp.float32)                     # (bk, dqk)
-    scores, mask = _block_scores_and_mask(
-        len_ref, cnt_ref, q, k, rab_ref, b=b, qi=qi, ki=ki, n_hist=n_hist,
-        bq=bq, bk=bk, max_rel=max_rel, use_rab=use_rab)
-
-    a = jax.nn.silu(scores) * (1.0 / scale_len)
-    a = jnp.where(mask, a, 0.0)
-    v = v_ref[0].astype(jnp.float32)                     # (bk, dv)
-    part = jax.lax.dot_general(a, v, (((1,), (0,)), ((), ())),
-                               preferred_element_type=jnp.float32)
-
-    @pl.when(ki == 0)
+    @pl.when(first)
     def _init():
         o_ref[0] = jnp.zeros_like(o_ref[0])
 
-    o_ref[0] += part.astype(o_ref.dtype)
+    @pl.when(live)
+    def _compute():
+        q = q_ref[0].astype(jnp.float32)                 # (bq, dqk)
+        k = k_ref[0].astype(jnp.float32)                 # (bk, dqk)
+        scores, mask = _block_scores_and_mask(
+            len_ref, cnt_ref, q, k, rab_ref, b=b, qi=qi, ki=ki,
+            n_hist=n_hist, bq=bq, bk=bk, max_rel=max_rel, use_rab=use_rab)
+        a = jax.nn.silu(scores) * (1.0 / scale_len)
+        a = jnp.where(mask, a, 0.0)
+        v = v_ref[0].astype(jnp.float32)                 # (bk, dv)
+        part = jax.lax.dot_general(a, v, (((1,), (0,)), ((), ())),
+                                   preferred_element_type=jnp.float32)
+        o_ref[0] += part.astype(o_ref.dtype)
 
 
-def _bwd_dq_kernel(len_ref, cnt_ref,
+def _bwd_dq_kernel(len_ref, cnt_ref, qi_ref, ki_ref, info_ref,
                    q_ref, k_ref, v_ref, rab_ref, do_ref,
                    dq_ref, drab_ref, *, n_hist: int, scale_len: int,
-                   n_heads: int, bq: int, bk: int, max_rel: int,
-                   use_rab: bool):
-    """dq (accumulated over k blocks) and the per-(b,h) gradient of the
-    bias row (accumulated over the whole q x k sub-grid)."""
-    bh = pl.program_id(0)
-    qi = pl.program_id(1)
-    ki = pl.program_id(2)
-    b = bh // n_heads
+                   n_heads: int, n_steps: int, bq: int, bk: int,
+                   max_rel: int, use_rab: bool):
+    """dq (accumulated over a row's tiles) and the per-(b,h) gradient of
+    the bias row (accumulated over every tile of the (b,h))."""
+    b, qi, ki, live, first = _step(qi_ref, ki_ref, info_ref,
+                                   n_heads=n_heads, n_steps=n_steps)
 
-    q = q_ref[0].astype(jnp.float32)
-    k = k_ref[0].astype(jnp.float32)
-    scores, mask = _block_scores_and_mask(
-        len_ref, cnt_ref, q, k, rab_ref, b=b, qi=qi, ki=ki, n_hist=n_hist,
-        bq=bq, bk=bk, max_rel=max_rel, use_rab=use_rab)
-
-    do = do_ref[0].astype(jnp.float32)                   # (bq, dv)
-    v = v_ref[0].astype(jnp.float32)                     # (bk, dv)
-    da = jax.lax.dot_general(do, v, (((1,), (1,)), ((), ())),
-                             preferred_element_type=jnp.float32)  # (bq, bk)
-    ds = da * (1.0 / scale_len) * _silu_grad(scores)
-    ds = jnp.where(mask, ds, 0.0)                        # dL/d(scores+bias)
-
-    dq_part = jax.lax.dot_general(ds, k, (((1,), (0,)), ((), ())),
-                                  preferred_element_type=jnp.float32)
-    dq_part = dq_part * (1.0 / math.sqrt(q.shape[-1]))
-
-    @pl.when(ki == 0)
+    @pl.when(first)
     def _init_dq():
         dq_ref[0] = jnp.zeros_like(dq_ref[0])
 
-    dq_ref[0] += dq_part.astype(dq_ref.dtype)
-
-    @pl.when((qi == 0) & (ki == 0))
+    @pl.when(pl.program_id(1) == 0)
     def _init_drab():
         drab_ref[0] = jnp.zeros_like(drab_ref[0])
 
-    if use_rab:
-        drab_ref[0] += _toeplitz_bias_grad(ds, qi * bq - ki * bk, bq=bq,
-                                           bk=bk, max_rel=max_rel)
+    @pl.when(live)
+    def _compute():
+        q = q_ref[0].astype(jnp.float32)
+        k = k_ref[0].astype(jnp.float32)
+        scores, mask = _block_scores_and_mask(
+            len_ref, cnt_ref, q, k, rab_ref, b=b, qi=qi, ki=ki,
+            n_hist=n_hist, bq=bq, bk=bk, max_rel=max_rel, use_rab=use_rab)
+        do = do_ref[0].astype(jnp.float32)               # (bq, dv)
+        v = v_ref[0].astype(jnp.float32)                 # (bk, dv)
+        da = jax.lax.dot_general(do, v, (((1,), (1,)), ((), ())),
+                                 preferred_element_type=jnp.float32)
+        ds = da * (1.0 / scale_len) * _silu_grad(scores)
+        ds = jnp.where(mask, ds, 0.0)                    # dL/d(scores+bias)
+        dq_part = jax.lax.dot_general(ds, k, (((1,), (0,)), ((), ())),
+                                      preferred_element_type=jnp.float32)
+        dq_part = dq_part * (1.0 / math.sqrt(q.shape[-1]))
+        dq_ref[0] += dq_part.astype(dq_ref.dtype)
+        if use_rab:
+            drab_ref[0] += _toeplitz_bias_grad(ds, qi * bq - ki * bk, bq=bq,
+                                               bk=bk, max_rel=max_rel)
 
 
-def _bwd_dkv_kernel(len_ref, cnt_ref,
+def _bwd_dkv_kernel(len_ref, cnt_ref, qi_ref, ki_ref, info_ref,
                     q_ref, k_ref, v_ref, rab_ref, do_ref,
                     dk_ref, dv_ref, *, n_hist: int, scale_len: int,
-                    n_heads: int, bq: int, bk: int, max_rel: int,
-                    use_rab: bool):
-    """dk and dv, both accumulated over q blocks (grid: q innermost)."""
-    bh = pl.program_id(0)
-    ki = pl.program_id(1)
-    qi = pl.program_id(2)
-    b = bh // n_heads
+                    n_heads: int, n_steps: int, bq: int, bk: int,
+                    max_rel: int, use_rab: bool):
+    """dk and dv, both accumulated over a column's tiles (column-major
+    table)."""
+    b, qi, ki, live, first = _step(qi_ref, ki_ref, info_ref,
+                                   n_heads=n_heads, n_steps=n_steps)
 
-    q = q_ref[0].astype(jnp.float32)
-    k = k_ref[0].astype(jnp.float32)
-    scores, mask = _block_scores_and_mask(
-        len_ref, cnt_ref, q, k, rab_ref, b=b, qi=qi, ki=ki, n_hist=n_hist,
-        bq=bq, bk=bk, max_rel=max_rel, use_rab=use_rab)
-
-    do = do_ref[0].astype(jnp.float32)                   # (bq, dv)
-    v = v_ref[0].astype(jnp.float32)                     # (bk, dv)
-
-    a = jax.nn.silu(scores) * (1.0 / scale_len)
-    a = jnp.where(mask, a, 0.0)
-    dv_part = jax.lax.dot_general(a, do, (((0,), (0,)), ((), ())),
-                                  preferred_element_type=jnp.float32)
-
-    da = jax.lax.dot_general(do, v, (((1,), (1,)), ((), ())),
-                             preferred_element_type=jnp.float32)  # (bq, bk)
-    ds = da * (1.0 / scale_len) * _silu_grad(scores)
-    ds = jnp.where(mask, ds, 0.0)
-    dk_part = jax.lax.dot_general(ds, q, (((0,), (0,)), ((), ())),
-                                  preferred_element_type=jnp.float32)
-    dk_part = dk_part * (1.0 / math.sqrt(q.shape[-1]))
-
-    @pl.when(qi == 0)
+    @pl.when(first)
     def _init():
         dk_ref[0] = jnp.zeros_like(dk_ref[0])
         dv_ref[0] = jnp.zeros_like(dv_ref[0])
 
-    dk_ref[0] += dk_part.astype(dk_ref.dtype)
-    dv_ref[0] += dv_part.astype(dv_ref.dtype)
+    @pl.when(live)
+    def _compute():
+        q = q_ref[0].astype(jnp.float32)
+        k = k_ref[0].astype(jnp.float32)
+        scores, mask = _block_scores_and_mask(
+            len_ref, cnt_ref, q, k, rab_ref, b=b, qi=qi, ki=ki,
+            n_hist=n_hist, bq=bq, bk=bk, max_rel=max_rel, use_rab=use_rab)
+        do = do_ref[0].astype(jnp.float32)               # (bq, dv)
+        v = v_ref[0].astype(jnp.float32)                 # (bk, dv)
+        a = jax.nn.silu(scores) * (1.0 / scale_len)
+        a = jnp.where(mask, a, 0.0)
+        dv_part = jax.lax.dot_general(a, do, (((0,), (0,)), ((), ())),
+                                      preferred_element_type=jnp.float32)
+        da = jax.lax.dot_general(do, v, (((1,), (1,)), ((), ())),
+                                 preferred_element_type=jnp.float32)
+        ds = da * (1.0 / scale_len) * _silu_grad(scores)
+        ds = jnp.where(mask, ds, 0.0)
+        dk_part = jax.lax.dot_general(ds, q, (((0,), (0,)), ((), ())),
+                                      preferred_element_type=jnp.float32)
+        dk_part = dk_part * (1.0 / math.sqrt(q.shape[-1]))
+        dk_ref[0] += dk_part.astype(dk_ref.dtype)
+        dv_ref[0] += dv_part.astype(dv_ref.dtype)
 
 
 # ---------------------------------------------------------------------------
@@ -307,7 +405,50 @@ def _flatten(q, k, v):
 def _rab_spec(rab_row, n_heads: int):
     """BlockSpec of the (H, 1, n) bias row: one head's whole row per step."""
     return pl.BlockSpec((1, 1, rab_row.shape[-1]),
-                        lambda bh, i, j, *s: (bh % n_heads, 0, 0))
+                        lambda bh, *rest: (bh % n_heads, 0, 0))
+
+
+def _tile_row(bh, t, len_ref, cnt_ref, qi_ref, ki_ref, info_ref):
+    """Index map of a block that follows the tile's row block."""
+    return bh, qi_ref[t], 0
+
+
+def _tile_col(bh, t, len_ref, cnt_ref, qi_ref, ki_ref, info_ref):
+    """Index map of a block that follows the tile's column block."""
+    return bh, ki_ref[t], 0
+
+
+def _streamed(n_heads: int, n_steps: int):
+    """Index map of the operands that change within a run of the table
+    (k and v along a row, q and dO along a column): the block
+    :func:`_step_info` chose for the step."""
+    def index(bh, t, len_ref, cnt_ref, qi_ref, ki_ref, info_ref):
+        return bh, info_ref[(bh // n_heads) * n_steps + t] >> 2, 0
+    return index
+
+
+def _step_info(hist_lengths, target_counts, *, n_hist: int, s_pad: int,
+               bq: int, bk: int, by_col: bool):
+    """The structural table (row-major, or column-major with ``by_col``)
+    and, per request and step, ``4 * block + 2 * first + live``, flattened:
+    ``live`` whether the tile is live for the request's lengths, ``first``
+    whether the step opens a run of tiles sharing its output block (a row,
+    or a column), ``block`` the block of the streamed operands (k, v along
+    a row; q, dO along a column). A dead step takes the block of the last
+    live step before it, or before any, of the first live one: the
+    pipeline copies a block only when its index changes, so a dead step
+    moves no data."""
+    qi, ki = _tile_table(n_hist, s_pad, bq, bk, by_col)
+    run, streamed = (ki, qi) if by_col else (qi, ki)
+    first = np.concatenate([[True], run[1:] != run[:-1]])
+    live = _tile_live(qi, ki, hist_lengths[:, None], target_counts[:, None],
+                      n_hist=n_hist, bq=bq, bk=bk)            # (B, n_steps)
+    steps = jnp.arange(len(qi))
+    last = jax.lax.cummax(jnp.where(live, steps, -1), axis=1)
+    src = jnp.where(last >= 0, last, jnp.argmax(live, axis=1)[:, None])
+    info = 4 * jnp.asarray(streamed)[src] + 2 * first + live
+    return (jnp.asarray(qi), jnp.asarray(ki),
+            info.astype(jnp.int32).reshape(-1))
 
 
 def _fwd_call(statics, hist_lengths, target_counts, q, k, v, rab_row):
@@ -315,30 +456,31 @@ def _fwd_call(statics, hist_lengths, target_counts, q, k, v, rab_row):
     b, h, s, dqk = q.shape
     dv = v.shape[-1]
     qf, kf, vf = _flatten(q, k, v)
-
-    grid = (b * h, s // bq, s // bk)
+    tables = _step_info(hist_lengths, target_counts, n_hist=n_hist, s_pad=s,
+                        bq=bq, bk=bk, by_col=False)
+    n_steps = tables[0].shape[0]
+    kv = _streamed(h, n_steps)
     kernel = functools.partial(
         _fwd_kernel, n_hist=n_hist, scale_len=scale_len, n_heads=h,
-        bq=bq, bk=bk, max_rel=max_rel, use_rab=use_rab)
+        n_steps=n_steps, bq=bq, bk=bk, max_rel=max_rel, use_rab=use_rab)
 
     out = pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=2,
-            grid=grid,
+            num_scalar_prefetch=5,
+            grid=(b * h, n_steps),
             in_specs=[
-                pl.BlockSpec((1, bq, dqk), lambda bh, qi, ki, *s: (bh, qi, 0)),
-                pl.BlockSpec((1, bk, dqk), lambda bh, qi, ki, *s: (bh, ki, 0)),
-                pl.BlockSpec((1, bk, dv), lambda bh, qi, ki, *s: (bh, ki, 0)),
+                pl.BlockSpec((1, bq, dqk), _tile_row),
+                pl.BlockSpec((1, bk, dqk), kv),
+                pl.BlockSpec((1, bk, dv), kv),
                 _rab_spec(rab_row, h),
             ],
-            out_specs=pl.BlockSpec((1, bq, dv),
-                                   lambda bh, qi, ki, *s: (bh, qi, 0)),
+            out_specs=pl.BlockSpec((1, bq, dv), _tile_row),
         ),
         out_shape=jax.ShapeDtypeStruct((b * h, s, dv), v.dtype),
         interpret=interpret,
         name="hstu_attn_fwd",
-    )(hist_lengths, target_counts, qf, kf, vf, rab_row)
+    )(hist_lengths, target_counts, *tables, qf, kf, vf, rab_row)
     return out.reshape(b, h, s, dv)
 
 
@@ -349,25 +491,29 @@ def _bwd_call(statics, hist_lengths, target_counts, q, k, v, rab_row, g):
     qf, kf, vf = _flatten(q, k, v)
     dof = g.reshape(b * h, s, dv)
     n_row = rab_row.shape[-1]
+    tables = functools.partial(_step_info, hist_lengths, target_counts,
+                               n_hist=n_hist, s_pad=s, bq=bq, bk=bk)
     kw = dict(n_hist=n_hist, scale_len=scale_len, n_heads=h, bq=bq, bk=bk,
               max_rel=max_rel, use_rab=use_rab)
 
-    in_specs_q_inner = [  # grid (bh, qi, ki)
-        pl.BlockSpec((1, bq, dqk), lambda bh, qi, ki, *s: (bh, qi, 0)),
-        pl.BlockSpec((1, bk, dqk), lambda bh, qi, ki, *s: (bh, ki, 0)),
-        pl.BlockSpec((1, bk, dv), lambda bh, qi, ki, *s: (bh, ki, 0)),
-        _rab_spec(rab_row, h),
-        pl.BlockSpec((1, bq, dv), lambda bh, qi, ki, *s: (bh, qi, 0)),
-    ]
+    rows = tables(by_col=False)
+    n_steps = rows[0].shape[0]
+    kv = _streamed(h, n_steps)
     dq_f, drab_f = pl.pallas_call(
-        functools.partial(_bwd_dq_kernel, **kw),
+        functools.partial(_bwd_dq_kernel, n_steps=n_steps, **kw),
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=2,
-            grid=(b * h, s // bq, s // bk),
-            in_specs=in_specs_q_inner,
+            num_scalar_prefetch=5,
+            grid=(b * h, n_steps),
+            in_specs=[
+                pl.BlockSpec((1, bq, dqk), _tile_row),
+                pl.BlockSpec((1, bk, dqk), kv),
+                pl.BlockSpec((1, bk, dv), kv),
+                _rab_spec(rab_row, h),
+                pl.BlockSpec((1, bq, dv), _tile_row),
+            ],
             out_specs=[
-                pl.BlockSpec((1, bq, dqk), lambda bh, qi, ki, *s: (bh, qi, 0)),
-                pl.BlockSpec((1, 1, n_row), lambda bh, qi, ki, *s: (bh, 0, 0)),
+                pl.BlockSpec((1, bq, dqk), _tile_row),
+                pl.BlockSpec((1, 1, n_row), lambda bh, *rest: (bh, 0, 0)),
             ],
         ),
         out_shape=[
@@ -376,25 +522,24 @@ def _bwd_call(statics, hist_lengths, target_counts, q, k, v, rab_row, g):
         ],
         interpret=interpret,
         name="hstu_attn_bwd_dq",
-    )(hist_lengths, target_counts, qf, kf, vf, rab_row, dof)
+    )(hist_lengths, target_counts, *rows, qf, kf, vf, rab_row, dof)
 
-    in_specs_k_inner = [  # grid (bh, ki, qi)
-        pl.BlockSpec((1, bq, dqk), lambda bh, ki, qi, *s: (bh, qi, 0)),
-        pl.BlockSpec((1, bk, dqk), lambda bh, ki, qi, *s: (bh, ki, 0)),
-        pl.BlockSpec((1, bk, dv), lambda bh, ki, qi, *s: (bh, ki, 0)),
-        _rab_spec(rab_row, h),
-        pl.BlockSpec((1, bq, dv), lambda bh, ki, qi, *s: (bh, qi, 0)),
-    ]
+    cols = tables(by_col=True)
+    qdo = _streamed(h, n_steps)
     dk_f, dv_f = pl.pallas_call(
-        functools.partial(_bwd_dkv_kernel, **kw),
+        functools.partial(_bwd_dkv_kernel, n_steps=n_steps, **kw),
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=2,
-            grid=(b * h, s // bk, s // bq),
-            in_specs=in_specs_k_inner,
-            out_specs=[
-                pl.BlockSpec((1, bk, dqk), lambda bh, ki, qi, *s: (bh, ki, 0)),
-                pl.BlockSpec((1, bk, dv), lambda bh, ki, qi, *s: (bh, ki, 0)),
+            num_scalar_prefetch=5,
+            grid=(b * h, n_steps),
+            in_specs=[
+                pl.BlockSpec((1, bq, dqk), qdo),
+                pl.BlockSpec((1, bk, dqk), _tile_col),
+                pl.BlockSpec((1, bk, dv), _tile_col),
+                _rab_spec(rab_row, h),
+                pl.BlockSpec((1, bq, dv), qdo),
             ],
+            out_specs=[pl.BlockSpec((1, bk, dqk), _tile_col),
+                       pl.BlockSpec((1, bk, dv), _tile_col)],
         ),
         out_shape=[
             jax.ShapeDtypeStruct((b * h, s, dqk), k.dtype),
@@ -402,7 +547,7 @@ def _bwd_call(statics, hist_lengths, target_counts, q, k, v, rab_row, g):
         ],
         interpret=interpret,
         name="hstu_attn_bwd_dkv",
-    )(hist_lengths, target_counts, qf, kf, vf, rab_row, dof)
+    )(hist_lengths, target_counts, *cols, qf, kf, vf, rab_row, dof)
 
     dq = dq_f.reshape(b, h, s, dqk)
     dk = dk_f.reshape(b, h, s, dqk)
@@ -457,6 +602,9 @@ def hstu_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
     bk = min(block_k, _round_up(s, 8))
     lcm = bq * bk // math.gcd(bq, bk)
     s_pad = -(-s // lcm) * lcm
+    grids = _TRACED_GRIDS.get()
+    if grids is not None:
+        grids.append(TileGrid(h, n_hist, s_pad, bq, bk))
     use_rab = rab is not None
     if rab is None:
         rab = jnp.zeros((h, 2 * max_rel_pos + 1), q.dtype)

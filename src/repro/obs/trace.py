@@ -71,6 +71,7 @@ def tracing_enabled() -> bool:
 class _NullSpan:
     """Shared no-op context manager — the disabled-mode fast path."""
     __slots__ = ()
+    recording = False      # args set on it go nowhere
 
     def __enter__(self):
         return self
@@ -91,6 +92,7 @@ class _Span:
     set), or both."""
     __slots__ = ("_tracer", "name", "cat", "args", "_profiled",
                  "_annotation", "_t0")
+    recording = True
 
     def __init__(self, tracer: Optional["Tracer"], name: str, cat: str,
                  args: Dict[str, Any], profiled: bool):

@@ -25,7 +25,9 @@ the full result set host-side twice.
 """
 from __future__ import annotations
 
+import collections
 import dataclasses
+import functools
 import threading
 import time
 from typing import (Callable, Dict, Hashable, Iterable, Iterator, List,
@@ -37,6 +39,7 @@ import numpy as np
 
 from repro.core.joiner import ROOSample
 from repro.data.batcher import BatcherConfig, BatchPlan, ROOBatcher
+from repro.kernels.hstu_attention import record_tile_grids
 from repro.obs import export as obs_export
 from repro.obs import metrics as obs_metrics
 from repro.obs import trace as obs_trace
@@ -201,9 +204,12 @@ class ScoringEngine:
         self.attn_backend = attn_backend
         self.clock = clock
         self.stats = EngineStats()
-        self._score = jax.jit(score_fn)
+        # fused HSTU kernels' tile grids per batch shape, recorded while the
+        # scoring halves trace (for the flush's attn_tiles_* args)
+        self._tile_grids: Dict[Tuple[int, int], list] = {}
+        self._score = jax.jit(self._recording_grids(score_fn))
         self._user = jax.jit(user_fn) if user_fn is not None else None
-        self._from_user = (jax.jit(score_from_user)
+        self._from_user = (jax.jit(self._recording_grids(score_from_user))
                            if score_from_user is not None else None)
         # param epoch versions every store entry; bumped on weight swap
         self._param_epoch = 0
@@ -224,6 +230,40 @@ class ScoringEngine:
         # circuit breaker: consecutive batch failures + open-until deadline
         self._breaker_failures = 0
         self._breaker_open_until: Optional[float] = None
+
+    def _recording_grids(self, fn: Callable) -> Callable:
+        """``fn`` recording, while jit traces it, the tile grid of every
+        fused HSTU kernel call it makes, under its batch's shape. A fresh
+        wrapper per engine, so each engine traces (and records) anew."""
+        @functools.wraps(fn)
+        def traced(params, batch, *rest):
+            with record_tile_grids() as grids:
+                out = fn(params, batch, *rest)
+            self._tile_grids[(batch.b_ro, batch.b_nro)] = grids
+            return out
+        return traced
+
+    def _attn_tiles(self, batch, samples: List[ROOSample],
+                    plan: BatchPlan) -> Tuple[int, int]:
+        """(live, dense) tiles of the fused HSTU kernels over the batch's
+        rows, heads and attention layers: each row's history length and
+        packed impressions (the ROO mask's ``hist_lengths`` and
+        ``target_counts``) through the kernels' live-tile predicate. (0, 0)
+        where no fused kernel was traced for this batch shape."""
+        grids = self._tile_grids.get((batch.b_ro, batch.b_nro))
+        if not grids:
+            return 0, 0
+        hl = np.zeros(batch.b_ro, np.int32)
+        tc = np.zeros(batch.b_ro, np.int32)
+        for p in plan.requests:
+            hl[p.row] = min(len(samples[p.request_index].history_ids),
+                            self.policy.hist_len)
+            tc[p.row] = p.n_packed
+        live = dense = 0
+        for g, n in collections.Counter(grids).items():
+            live += n * g.n_heads * int(g.live_tiles(hl, tc).sum())
+            dense += n * g.n_heads * batch.b_ro * g.dense_tiles
+        return live, dense
 
     @classmethod
     def from_scenario(cls, spec, params=None, rng_seed: int = 0,
@@ -435,7 +475,11 @@ class ScoringEngine:
         rung's ``b_ro`` and ``b_nro``, ``history_events`` (each row's
         history up to ``hist_len``), ``history_slots`` (``b_ro x hist_len``
         over its batches) and, on the online path, ``queued_ms_sum`` /
-        ``queued_ms_max`` (the flush's start less each ``submit``)."""
+        ``queued_ms_max`` (the flush's start less each ``submit``) and,
+        while the span records and the model runs the fused HSTU kernels,
+        ``attn_tiles_live`` / ``attn_tiles_grid``: the tiles those kernels
+        compute and the tiles of their dense grid, over the batches' rows,
+        heads and layers (``_attn_tiles``)."""
         hist_len = self.policy.hist_len
         n_imps = sum(s.num_impressions for _, s in group)
         bucket = self.ladder.select(len(group), n_imps)
@@ -455,6 +499,7 @@ class ScoringEngine:
                 samples = [s for _, s in group]
                 plans = list(batcher.batches_with_plan(samples))
             fspan.set(history_slots=len(plans) * bucket.b_ro * hist_len)
+            tiles_live = tiles_grid = 0
             for batch, plan in plans:
                 if self._breaker_sheds():
                     for p in plan.requests:
@@ -480,6 +525,10 @@ class ScoringEngine:
                 self._breaker_failures = 0
                 self._breaker_open_until = None
                 self.stats.inc("n_batches")
+                if fspan.recording:
+                    live, grid = self._attn_tiles(batch, samples, plan)
+                    tiles_live += live
+                    tiles_grid += grid
                 for p in plan.requests:
                     if p.n_dropped:
                         raise RuntimeError(
@@ -487,6 +536,9 @@ class ScoringEngine:
                             f"bucket-shaped batch ({p.n_dropped} dropped)")
                     yield (group[p.request_index][0],
                            scores[p.slot_start:p.slot_start + p.n_packed])
+            if tiles_grid:
+                fspan.set(attn_tiles_live=tiles_live,
+                          attn_tiles_grid=tiles_grid)
         obs_export.maybe_emit("serve.flush")
 
     # ---- circuit breaker -----------------------------------------------------

@@ -24,6 +24,11 @@ def _ragged_case(seed, b, h, s, dqk, dv, n_hist, use_rab, tc_min=0):
         else None
     hl = jax.random.randint(ks[4], (b,), 0, n_hist + 1)
     tc = jax.random.randint(ks[5], (b,), tc_min, s - n_hist + 1)
+    if b >= 3:
+        # rows 0-2: empty, a full history with every target, and lengths
+        # off the 64-block edge
+        hl = hl.at[:3].set(jnp.asarray([0, n_hist, 129]))
+        tc = tc.at[:3].set(jnp.asarray([0, s - n_hist, 1]))
     w = jax.random.normal(ks[6], (b, h, s, dv))
     return q, k, v, rab, hl, tc, w
 
@@ -36,6 +41,7 @@ class TestForwardParity:
         (2, 2, 100, 32, 16, 80),     # non-128-multiple -> pad-and-crop
         (1, 2, 65, 32, 32, 64),      # s < block, m_targets = 1
         (2, 1, 48, 16, 16, 48),      # pure causal (no target slots)
+        (4, 2, 200, 32, 32, 184),    # edge rows (see _ragged_case)
     ])
     def test_matches_dense_oracle(self, backend, use_rab, b, h, s, dqk, dv,
                                   n_hist):
@@ -62,6 +68,7 @@ class TestGradientParity:
     @pytest.mark.parametrize("b,h,s,dqk,dv,n_hist", [
         (2, 2, 128, 32, 32, 96),
         (2, 2, 100, 32, 16, 80),     # pad-and-crop in the backward too
+        (4, 2, 200, 32, 32, 184),    # edge rows (see _ragged_case)
     ])
     def test_grads_match_oracle(self, backend, use_rab, b, h, s, dqk, dv,
                                 n_hist):

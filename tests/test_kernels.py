@@ -9,7 +9,8 @@ from hypothesis import given, settings, strategies as st
 from repro.kernels import ref
 from repro.kernels.dot_interaction import dot_interaction
 from repro.kernels.embedding_bag import embedding_bag
-from repro.kernels.hstu_attention import hstu_attention
+from repro.core.masks import roo_batch_mask
+from repro.kernels.hstu_attention import _tile_live, _tile_table, hstu_attention
 
 
 class TestHSTUAttention:
@@ -54,6 +55,40 @@ class TestHSTUAttention:
         a = hstu_attention(*args, block_q=64, block_k=64)
         b = hstu_attention(*args, block_q=128, block_k=256)
         np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=1e-5)
+
+    @pytest.mark.parametrize("tc_of", ["none", "one", "all"])
+    @pytest.mark.parametrize("n_hist,s", [(496, 512),    # on the lattice
+                                          (500, 516)])   # padded to it
+    @pytest.mark.parametrize("bq,bk", [(64, 64), (128, 128), (128, 256)])
+    def test_tile_live_matches_dense_mask(self, bq, bk, n_hist, s, tc_of):
+        """``_tile_live`` is true on exactly the tiles where the dense ROO
+        mask (over the padded lattice, as the kernels see it) holds a true
+        cell, in-kernel (jnp) and on the host (numpy); the structural table
+        holds every tile live for any lengths."""
+        lcm = bq * bk // np.gcd(bq, bk)
+        s_pad = -(-s // lcm) * lcm
+        hl = np.asarray([0, 1, 127, 128, 129, n_hist // 2 + 37, n_hist])
+        tc = np.full_like(hl, {"none": 0, "one": 1, "all": s - n_hist}[tc_of])
+        dense = np.asarray(roo_batch_mask(jnp.asarray(hl), jnp.asarray(tc),
+                                          n_hist, s_pad - n_hist))
+        want = dense.reshape(len(hl), s_pad // bq, bq, s_pad // bk, bk) \
+            .any(axis=(2, 4))                                   # (B, nq, nk)
+        qi, ki = np.meshgrid(np.arange(s_pad // bq), np.arange(s_pad // bk),
+                             indexing="ij")
+        kw = dict(n_hist=n_hist, bq=bq, bk=bk)
+        host = _tile_live(qi[None], ki[None], hl[:, None, None],
+                          tc[:, None, None], xp=np, **kw)
+        np.testing.assert_array_equal(host, want)
+        dev = _tile_live(jnp.asarray(qi)[None], jnp.asarray(ki)[None],
+                         jnp.asarray(hl)[:, None, None],
+                         jnp.asarray(tc)[:, None, None], **kw)
+        np.testing.assert_array_equal(np.asarray(dev), want)
+        for by_col in (False, True):
+            tq, tk = _tile_table(n_hist, s_pad, bq, bk, by_col)
+            table = np.zeros(want.shape[1:], bool)
+            table[tq, tk] = True
+            assert not (want & ~table).any()
+            assert len(tq) == table.sum()
 
 
 class TestEmbeddingBag:

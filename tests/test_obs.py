@@ -11,6 +11,7 @@ Covers the obs contracts the rest of the repo leans on:
   * the JSONL telemetry emitter round-trips and rate-limits;
   * structured logging + warn-once suppression.
 """
+import dataclasses
 import json
 import threading
 
@@ -357,6 +358,48 @@ class TestEngineTracePropagation:
         names = {e[0] for e in events}
         assert {"repro.engine.bucket", "repro.engine.score",
                 "repro.engine.dispatch", "repro.engine.fetch"} <= names
+
+    def test_flush_counts_the_attention_tiles(self, tmp_path):
+        """A model running the fused HSTU kernel: each flush carries the
+        tiles the kernel computes (``attn_tiles_live``) and its dense grid
+        (``attn_tiles_grid``), counted from the rows' lengths; the live
+        count equals the tiles of the dense ROO mask that hold a true cell,
+        over rows, heads and layers. A second engine around the same
+        function records its own grids."""
+        from repro.core.masks import roo_batch_mask
+        from repro.kernels.hstu_attention import hstu_attention
+        from repro.serve.engine import EnginePolicy, ScoringEngine
+        n_hist, m, h, blk, layers = 64, 4, 2, 16, 2
+
+        def score(p, b):
+            x = jnp.ones((b.b_ro, h, n_hist + m, 8))
+            for _ in range(layers):
+                x = hstu_attention(x, x, x, None, n_hist, b.history_lengths,
+                                   b.num_impressions, block_q=blk,
+                                   block_k=blk)
+            return b.item_ids.astype(jnp.float32) + 0.0 * x.sum()
+
+        hist = [0, 3, 17, 40]
+        reqs = [dataclasses.replace(
+            _mk_request(i, list(range(1, 2 + i % 3))),
+            history_ids=list(range(1, 1 + n)),
+            history_actions=[0] * n) for i, n in enumerate(hist)]
+        # the dense mask over the padded lattice, per 16 x 16 tile
+        s_pad = 80
+        hl = jnp.asarray(hist)
+        tc = jnp.asarray([r.num_impressions for r in reqs])
+        dense = np.asarray(roo_batch_mask(hl, tc, n_hist, s_pad - n_hist))
+        live = dense.reshape(4, 5, blk, 5, blk).any(axis=(2, 4)).sum()
+        for _ in range(2):
+            engine = ScoringEngine(None, score, policy=EnginePolicy(
+                max_requests=4, max_impressions=16, hist_len=n_hist))
+            events = _profiled(tmp_path / str(_),
+                               lambda: engine.score_requests(reqs))
+            flush, = [e[4] for e in events if e[0] == "repro.engine.flush"]
+            assert flush["b_ro"] == 4
+            assert flush["attn_tiles_grid"] == layers * h * 4 * 5 * 5
+            assert flush["attn_tiles_live"] == layers * h * live
+            assert flush["attn_tiles_live"] < flush["attn_tiles_grid"]
 
     def test_one_snapshot_sees_the_whole_stack(self, metrics_on):
         # the tentpole contract: serving + pipeline + training +

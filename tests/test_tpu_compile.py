@@ -12,6 +12,7 @@ The topology is described inside a module fixture, never at import: only
 one process at a time may load the TPU library, and every test worker
 imports this file.
 """
+import importlib.util
 import os
 import re
 
@@ -95,6 +96,50 @@ def test_kernel_names_reach_the_compiled_hlo(one_chip):
     text = _compile(_hstu_fwd_bwd, *args).as_text()
     for name in ("hstu_attn_fwd", "hstu_attn_bwd_dq", "hstu_attn_bwd_dkv"):
         assert re.search(rf"%\w*{name}_*\.\d+ = ", text), name
+
+
+def _roofline_kernels_regex(cell: str) -> str:
+    """``KERNELS`` of the benchmark's roofline reader for ``cell``."""
+    path = os.path.join(os.path.dirname(__file__), os.pardir, "chipbench",
+                        "metrics", f"hstu_attn_roofline.{cell}.py")
+    spec = importlib.util.spec_from_file_location(f"roofline_{cell}", path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod.KERNELS
+
+
+def _kernel_lines(compiled):
+    """The compiled module's Pallas kernel instructions, printed with their
+    operands' shapes, as a profiler trace names its ``XLA Ops`` events."""
+    from jax._src.lib import xla_client
+    opts = xla_client._xla.HloPrintOptions()
+    opts.print_operand_shape = True
+    opts.print_metadata = False
+    text = "\n".join(m.to_string(opts)
+                     for m in compiled.runtime_executable().hlo_modules())
+    return [ln.strip() for ln in text.splitlines() if "tpu_custom_call" in ln]
+
+
+@pytest.mark.parametrize("cell", ["train", "serve"])
+def test_kernels_match_the_roofline_readers(one_chip, cell):
+    """The roofline readers find the HSTU kernels by their first two
+    operands, the (B,) int32 history lengths and target counts; the tile
+    tables come after them. Every fused kernel, forward and backward, still
+    matches, under its own name."""
+    kernels = _roofline_kernels_regex(cell)
+    args = _hstu_args(one_chip)
+    fwd = _kernel_lines(_compile(_hstu, *args))
+    assert len(fwd) == 1 and fwd[0].startswith("%hstu_attn_fwd.")
+    both = _kernel_lines(_compile(_hstu_fwd_bwd, *args))
+    assert len(both) == 3
+    for name in ("hstu_attn_fwd", "hstu_attn_bwd_dq", "hstu_attn_bwd_dkv"):
+        line, = [ln for ln in both if re.match(rf"%\w*{name}_*\.\d+ = ", ln)]
+        assert re.search(kernels, line), line[:300]
+    lengths_first = re.compile(
+        rf"custom-call\(s32\[{B}\]\{{[^}}]*\}} %[\w.-]+, s32\[{B}\]\{{")
+    for line in fwd + both:
+        assert re.search(kernels, line), line[:300]
+        assert lengths_first.search(line), line[:300]
 
 
 @pytest.mark.parametrize("n_new", [1, 1024])
