@@ -20,7 +20,9 @@ Phases, one line each (phase, compile seconds, steady seconds, checks):
                and then incremental on repeat users (the prefix kernel);
                every request scored, no ``ScoreError``, incremental scores
                equal to stateless ones within the kernel tolerance.
-  (d) dlrm     dlrm-mlperf as registered, 5 steps (the bag kernel trains).
+  (d) dlrm     dlrm-mlperf as registered (published widths, tables capped
+               at ``model.n_items`` rows), 5 steps on ``ROOBatch``es (the
+               bag kernel trains).
 
 The last line of stdout is one JSON object naming the device. The script
 exits non-zero, printing no result, unless JAX's first device is a TPU and
@@ -320,15 +322,14 @@ def phase_serve(spec, params, n_requests: int = 16) -> None:
 
 
 def phase_dlrm() -> None:
-    import jax
-
     from repro.configs.registry import scenario
-    from repro.scenario.build import build_model, synthetic_dlrm_batches
+    from repro.data.batcher import ROOBatcher
+    from repro.scenario.build import build_batcher_cfg, build_samples
     spec = scenario("dlrm-mlperf", {"train.steps": 5, "train.log_every": 1,
                                     "train.halt_after_skips": 1})
     trainer, state, losses, c, st = _train(spec)
-    cfg = build_model(spec, jax.random.PRNGKey(0)).cfg
-    batch = synthetic_dlrm_batches(spec, cfg)[0]
+    batch = next(ROOBatcher(build_batcher_cfg(spec)).batches(
+        build_samples(spec)))
     _check(_step_has_kernel(trainer, state, batch),
            "no Pallas kernel in the dlrm-mlperf train step")
     _report("d_dlrm", c, st, {"steps": len(losses),

@@ -70,11 +70,10 @@ def scenario(arch_id: str, overrides: Optional[Mapping] = None):
     elif arch_id == "dien":
         model = ModelSpec(arch=arch_id, seq_len=64)
     elif arch_id == "dlrm-mlperf":
-        # MLPerf-shaped at reduced scale; field-dict batches come from the
-        # synthetic generator, not the ROO event stream
-        model = ModelSpec(arch=arch_id, n_items=0, embed_dim=16)
+        # DLRMConfig's published widths; n_items caps every table's rows
+        # (and sizes the stream), so the CPU smoke run holds the tables
+        model = ModelSpec(arch=arch_id, n_items=1000)
         batcher = BatcherSpec(b_ro=8, b_nro=32)
-        data = DataSpec(source="synthetic")
     spec = ScenarioSpec(name=arch_id, model=model, batcher=batcher,
                         data=data).validate()
     return spec.with_overrides(overrides) if overrides else spec

@@ -1,22 +1,35 @@
 """DLRM (Naumov et al. 2019) — MLPerf benchmark config, ROO-capable.
 
 Assigned config (dlrm-mlperf): 13 dense features, 26 sparse fields,
-embed_dim=128, bottom MLP 13-512-256-128, top MLP 1024-1024-512-256-1,
-dot interaction, Criteo-1TB-scale vocabs.
+embed_dim=128, bottom MLP 13-512-256-128 (ReLU after every layer, as in
+the DLRM reference), dot interaction over 27 vectors
+(128 + 351 = 479 features), top MLP 479-1024-1024-512-256-1, Criteo-1TB
+vocabs capped at 40M rows (MLPerf Training v1).
 
 ROO applicability (DESIGN.md §4): the 13 dense features and the user-side
 subset of sparse fields are RO; item-side fields are NRO. Under ROO the
 bottom MLP + RO lookups run at B_RO and fan out at the interaction.
+
+A ``ROOBatch`` carries the fields in a fixed order: each request row of
+``ro_sparse["user_ids"]`` holds its ``n_ro_fields * multi_hot`` ids, field
+by field, and each impression slot of ``nro_sparse["item_cats"]`` the
+item-side fields' ids the same way; padding rows hold none
+(``dlrm_sample`` writes a sample in that layout, ``dlrm_batcher_config``
+sizes the batcher for it).
 """
 from __future__ import annotations
 
 import dataclasses
-from typing import Dict, Tuple
+from typing import Dict, List, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from repro.core.fanout import fanout
+from repro.core.joiner import ROOSample
+from repro.core.roo_batch import ROOBatch
+from repro.data.batcher import BatcherConfig
 from repro.embeddings.collection import (EmbeddingCollection,
                                          EmbeddingCollectionConfig,
                                          FeatureSpec, TableConfig,
@@ -24,8 +37,8 @@ from repro.embeddings.collection import (EmbeddingCollection,
 from repro.models.interactions import dot_interaction
 from repro.models.mlp import mlp_apply, mlp_init
 
-# MLPerf Criteo-1TB row counts (capped variant used by the reference v1
-# benchmark; total ~882M rows at dim 128).
+# MLPerf Criteo-1TB row counts (capped at 40M rows, the v1 reference's
+# max-ind-range; 187,767,399 rows in all, 96.1 GB in float32 at dim 128).
 MLPERF_VOCABS = (
     39884406, 39043, 17289, 7420, 20263, 3, 7120, 1543, 63, 38532951,
     2953546, 403346, 10, 2208, 11938, 155, 4, 976, 14, 39979771,
@@ -36,8 +49,8 @@ MLPERF_VOCABS = (
 class DLRMConfig:
     n_dense: int = 13
     embed_dim: int = 128
-    bot_mlp: Tuple[int, ...] = (13, 512, 256, 128)
-    top_mlp: Tuple[int, ...] = (1024, 1024, 512, 256, 1)
+    bot_mlp: Tuple[int, ...] = (13, 512, 256, 128)   # input first
+    top_mlp: Tuple[int, ...] = (1024, 1024, 512, 256, 1)  # after top_in_dim
     vocabs: Tuple[int, ...] = MLPERF_VOCABS
     n_ro_fields: int = 13       # first k sparse fields treated as user-side
     multi_hot: int = 1          # ids per field (MLPerf v1 is one-hot)
@@ -74,13 +87,16 @@ class DLRMConfig:
         return self.embed_dim + f * (f - 1) // 2
 
 
+def _top_dims(cfg: DLRMConfig) -> Tuple[int, ...]:
+    return (cfg.top_in_dim(),) + cfg.top_mlp
+
+
 def dlrm_init(rng: jax.Array, cfg: DLRMConfig, dtype=jnp.float32) -> Dict:
     k1, k2, k3 = jax.random.split(rng, 3)
-    top_dims = (cfg.top_in_dim(),) + cfg.top_mlp[1:]
     return {
         "tables": cfg.collection().init(k1, dtype),
         "bot_mlp": mlp_init(k2, cfg.bot_mlp, dtype),
-        "top_mlp": mlp_init(k3, top_dims, dtype),
+        "top_mlp": mlp_init(k3, _top_dims(cfg), dtype),
     }
 
 
@@ -116,12 +132,17 @@ def dlrm_forward_from_embs(params: Dict, cfg: DLRMConfig,
     Split out so the sparse-update training path can differentiate wrt the
     gathered rows instead of the full tables.
     """
-    dense_out = mlp_apply(params["bot_mlp"], ro_dense)            # (B_RO, D)
-    ro_pack = jnp.concatenate([dense_out[:, None, :], ro_embs], axis=1)
-    ro_at_nro = fanout(ro_pack, segment_ids)                      # one fanout
-    sparse = jnp.concatenate([ro_at_nro[:, 1:, :], nro_embs], axis=1)
-    z = dot_interaction(ro_at_nro[:, 0, :], sparse)
-    return mlp_apply(params["top_mlp"], z)[:, 0]
+    with jax.named_scope("dlrm.bottom_mlp"):
+        dense_out = mlp_apply(params["bot_mlp"], ro_dense,      # (B_RO, D)
+                              final_activation=jax.nn.relu)
+    with jax.named_scope("dlrm.fanout"):
+        ro_pack = jnp.concatenate([dense_out[:, None, :], ro_embs], axis=1)
+        ro_at_nro = fanout(ro_pack, segment_ids)                  # one fanout
+    with jax.named_scope("dlrm.interaction"):
+        sparse = jnp.concatenate([ro_at_nro[:, 1:, :], nro_embs], axis=1)
+        z = dot_interaction(ro_at_nro[:, 0, :], sparse)
+    with jax.named_scope("dlrm.top_mlp"):
+        return mlp_apply(params["top_mlp"], z)[:, 0]
 
 
 def dlrm_forward_roo(params: Dict, cfg: DLRMConfig,
@@ -134,11 +155,12 @@ def dlrm_forward_roo(params: Dict, cfg: DLRMConfig,
     ro_dense: (B_RO, 13); ro_ids: (B_RO, n_ro_fields, mh);
     nro_ids: (B_NRO, n_nro_fields, mh). Returns (B_NRO,) logits.
     """
-    ro_fields = range(cfg.n_ro_fields)
-    nro_fields = range(cfg.n_ro_fields, cfg.n_sparse)
-    ro_embs = _field_lookup(params, cfg, ro_ids, ro_lengths, ro_fields, plan)
-    nro_embs = _field_lookup(params, cfg, nro_ids, nro_lengths, nro_fields,
-                             plan)
+    with jax.named_scope("dlrm.lookup_ro"):
+        ro_embs = _field_lookup(params, cfg, ro_ids, ro_lengths,
+                                range(cfg.n_ro_fields), plan)
+    with jax.named_scope("dlrm.lookup_nro"):
+        nro_embs = _field_lookup(params, cfg, nro_ids, nro_lengths,
+                                 range(cfg.n_ro_fields, cfg.n_sparse), plan)
     return dlrm_forward_from_embs(params, cfg, ro_dense, ro_embs, nro_embs,
                                   segment_ids)
 
@@ -150,17 +172,120 @@ def dlrm_forward_impression(params: Dict, cfg: DLRMConfig,
 
     dense: (B, 13); ids: (B, 26, mh). Returns (B,) logits.
     """
-    dense_out = mlp_apply(params["bot_mlp"], dense)
-    embs = _field_lookup(params, cfg, ids, lengths, range(cfg.n_sparse), plan)
-    z = dot_interaction(dense_out, embs)
-    return mlp_apply(params["top_mlp"], z)[:, 0]
+    n = cfg.n_ro_fields
+    with jax.named_scope("dlrm.bottom_mlp"):
+        dense_out = mlp_apply(params["bot_mlp"], dense,
+                              final_activation=jax.nn.relu)
+    with jax.named_scope("dlrm.lookup_ro"):
+        ro_embs = _field_lookup(params, cfg, ids[:, :n], lengths[:, :n],
+                                range(n), plan)
+    with jax.named_scope("dlrm.lookup_nro"):
+        nro_embs = _field_lookup(params, cfg, ids[:, n:], lengths[:, n:],
+                                 range(n, cfg.n_sparse), plan)
+    with jax.named_scope("dlrm.interaction"):
+        z = dot_interaction(dense_out,
+                            jnp.concatenate([ro_embs, nro_embs], axis=1))
+    with jax.named_scope("dlrm.top_mlp"):
+        return mlp_apply(params["top_mlp"], z)[:, 0]
 
 
-def dlrm_table_ids(cfg: DLRMConfig, ro_ids: jnp.ndarray,
-                   nro_ids: jnp.ndarray) -> Dict[str, jnp.ndarray]:
+# ---------------------------------------------------------------------------
+# ROOBatch entry points
+# ---------------------------------------------------------------------------
+
+# the id-list keys ROOBatcher packs (data/batcher.py)
+RO_KEY, NRO_KEY = "user_ids", "item_cats"
+
+
+def _mix64(x: np.ndarray) -> np.ndarray:
+    """splitmix64's finalizer: a fixed, well-spread map of uint64s."""
+    with np.errstate(over="ignore"):
+        x = x ^ (x >> np.uint64(30))
+        x = x * np.uint64(0xBF58476D1CE4E5B9)
+        x = x ^ (x >> np.uint64(27))
+        x = x * np.uint64(0x94D049BB133111EB)
+        return x ^ (x >> np.uint64(31))
+
+
+def _hashed_ids(cfg: DLRMConfig, key: int, fields: range) -> List[int]:
+    mh = cfg.multi_hot
+    slots = np.arange(fields.start * mh, fields.stop * mh, dtype=np.uint64)
+    vocab = np.repeat(np.asarray(cfg.vocabs[fields.start:fields.stop],
+                                 np.uint64), mh)
+    return (_mix64((np.uint64(key) << np.uint64(16)) | slots)
+            % vocab).astype(np.int64).tolist()
+
+
+def dlrm_sample(sample: ROOSample, cfg: DLRMConfig) -> ROOSample:
+    """An event-stream sample in DLRM's field layout: its first ``n_dense``
+    dense features (zero-padded), the user-side fields' ids hashed from its
+    user id and each impression's item-side ids from its item id, so a user
+    or an item keeps its rows across requests. The history is dropped:
+    DLRM reads none."""
+    dense = np.zeros((cfg.n_dense,), np.float32)
+    ro = np.asarray(sample.ro_dense, np.float32).ravel()[:cfg.n_dense]
+    dense[:ro.shape[0]] = ro
+    nro = range(cfg.n_ro_fields, cfg.n_sparse)
+    return dataclasses.replace(
+        sample, ro_dense=dense,
+        ro_idlist=_hashed_ids(cfg, sample.user_id, range(cfg.n_ro_fields)),
+        history_ids=[], history_actions=[],
+        item_idlist=[_hashed_ids(cfg, item, nro) for item in sample.item_ids])
+
+
+def dlrm_batcher_config(cfg: DLRMConfig, base: BatcherConfig
+                        ) -> BatcherConfig:
+    """``base`` with id-list capacities that hold every field id of a full
+    batch (smaller ones would truncate), and no history window."""
+    mh = cfg.multi_hot
+    return dataclasses.replace(
+        base, hist_len=0,
+        ro_idlist_capacity=base.b_ro * cfg.n_ro_fields * mh,
+        item_idlist_capacity=base.b_nro * (cfg.n_sparse - cfg.n_ro_fields)
+        * mh)
+
+
+def dlrm_batch_fields(cfg: DLRMConfig, batch: ROOBatch):
+    """(ro_ids (B_RO, n_ro_fields, mh), ro_lengths (B_RO, n_ro_fields),
+    nro_ids (B_NRO, n_nro_fields, mh), nro_lengths) of a batch in DLRM's
+    field layout; a padding row's bags are empty."""
+    mh = cfg.multi_hot
+
+    def fields(jt, n):
+        ids, mask = jt.to_padded(n * mh)
+        b = ids.shape[0]
+        return (ids.reshape(b, n, mh),
+                jnp.sum(mask.reshape(b, n, mh), axis=-1, dtype=jnp.int32))
+    return (fields(batch.ro_sparse[RO_KEY], cfg.n_ro_fields)
+            + fields(batch.nro_sparse[NRO_KEY],
+                     cfg.n_sparse - cfg.n_ro_fields))
+
+
+def dlrm_logits_roo(params: Dict, cfg: DLRMConfig, batch: ROOBatch,
+                    plan=None) -> jnp.ndarray:
+    """(B_NRO,) click logits of a ROOBatch (``dlrm_forward_roo``)."""
+    ro_ids, ro_len, nro_ids, nro_len = dlrm_batch_fields(cfg, batch)
+    return dlrm_forward_roo(params, cfg, batch.ro_dense, ro_ids, ro_len,
+                            nro_ids, nro_len, batch.segment_ids, plan=plan)
+
+
+def dlrm_loss(params: Dict, cfg: DLRMConfig, batch: ROOBatch,
+              plan=None) -> jnp.ndarray:
+    """Binary cross-entropy of the click logit (``labels[:, 0]``), the mean
+    over the batch's real impressions."""
+    logits = dlrm_logits_roo(params, cfg, batch, plan=plan)
+    y = batch.labels[:, 0].astype(logits.dtype)
+    w = batch.impression_mask().astype(logits.dtype)
+    bce = (jnp.maximum(logits, 0) - logits * y
+           + jnp.log1p(jnp.exp(-jnp.abs(logits))))
+    return jnp.sum(bce * w) / jnp.maximum(jnp.sum(w), 1.0)
+
+
+def dlrm_table_ids(cfg: DLRMConfig, batch: ROOBatch) -> Dict[str, jnp.ndarray]:
     """Per-table flat id sets of one ROO batch (params-tree paths), for
     ``embeddings.sparse.make_sparse_value_and_grad`` — folded through the
     collection's feature routing so declaration and lookup cannot drift."""
+    ro_ids, _, nro_ids, _ = dlrm_batch_fields(cfg, batch)
     feats = {}
     for j, f in enumerate(range(cfg.n_ro_fields)):
         feats[f"f{f}"] = ro_ids[:, j]
@@ -173,6 +298,5 @@ def dlrm_flops_per_example(cfg: DLRMConfig) -> int:
     """Analytic dense forward FLOPs per impression (impression-level)."""
     from repro.models.mlp import mlp_flops
     f = cfg.n_sparse + 1
-    top_dims = (cfg.top_in_dim(),) + cfg.top_mlp[1:]
-    return (mlp_flops(cfg.bot_mlp, 1) + mlp_flops(top_dims, 1)
+    return (mlp_flops(cfg.bot_mlp, 1) + mlp_flops(_top_dims(cfg), 1)
             + 2 * f * f * cfg.embed_dim)

@@ -23,7 +23,7 @@ import dataclasses
 import hashlib
 import json
 import os
-from typing import Any, Callable, Dict, List, NamedTuple, Optional
+from typing import Any, Callable, List, NamedTuple, Optional
 
 from repro.scenario.spec import ScenarioSpec, ScenarioValidationError
 # ServeAdapter moved to repro.serve.adapter in PR 9 (first-class serving
@@ -67,18 +67,42 @@ def build_stream_cfg(spec: ScenarioSpec):
                          spec.batcher.hist_len))
 
 
+def _dlrm_layout(spec: ScenarioSpec):
+    from repro.models.dlrm import dlrm_batcher_config, dlrm_sample
+    cfg = dlrm_config(spec)
+    return (lambda base: dlrm_batcher_config(cfg, base),
+            lambda s: dlrm_sample(s, cfg))
+
+
+# archs whose model reads the joined samples in a field layout of its own:
+# spec -> (batcher config from the spec's sizes, sample mapping)
+FIELD_LAYOUTS = {"dlrm-mlperf": _dlrm_layout}
+
+
 def build_batcher_cfg(spec: ScenarioSpec, n_shards: int = 1):
     from repro.data.batcher import BatcherConfig
-    return BatcherConfig(b_ro=spec.batcher.b_ro, b_nro=spec.batcher.b_nro,
-                         hist_len=spec.batcher.hist_len, n_shards=n_shards)
+    cfg = BatcherConfig(b_ro=spec.batcher.b_ro, b_nro=spec.batcher.b_nro,
+                        hist_len=spec.batcher.hist_len, n_shards=n_shards)
+    layout = FIELD_LAYOUTS.get(spec.model.arch)
+    return layout(spec)[0](cfg) if layout else cfg
+
+
+def model_samples(spec: ScenarioSpec, samples: List) -> List:
+    """Joined samples in the layout the spec's model reads."""
+    layout = FIELD_LAYOUTS.get(spec.model.arch)
+    if layout is None:
+        return samples
+    to_model = layout(spec)[1]
+    return [to_model(s) for s in samples]
 
 
 def build_samples(spec: ScenarioSpec) -> List:
-    """Deterministic in-memory ROO samples for the spec's event stream."""
+    """Deterministic in-memory ROO samples for the spec's event stream, in
+    the layout its model reads."""
     from repro.core.joiner import RequestLevelJoiner
     from repro.data.events import EventSimulator
-    return RequestLevelJoiner().join(
-        list(EventSimulator(build_stream_cfg(spec)).stream()))
+    return model_samples(spec, RequestLevelJoiner().join(
+        list(EventSimulator(build_stream_cfg(spec)).stream())))
 
 
 # ---------------------------------------------------------------------------
@@ -265,66 +289,25 @@ def build_model(spec: ScenarioSpec, rng, plan=None,
             _ne_metrics(lambda p, b: (dien_logits_roo(p, cfg, b),
                                       b.labels[:, 0], b.impression_mask())),
             ServeAdapter(score=lambda p, b: dien_logits_roo(p, cfg, b)))
-    # dlrm-mlperf: MLPerf-shaped at reduced scale (the full vocabs are
-    # hundreds of millions of rows — dry-run cells only). Field-dict
-    # batches, not ROOBatch, so it is synthetic-data-only + not servable
-    # through the ROO engine.
-    from repro.models.dlrm import (DLRMConfig, dlrm_forward_roo, dlrm_init,
+    # dlrm-mlperf: the published widths, tables capped at model.n_items rows
+    from repro.models.dlrm import (dlrm_init, dlrm_logits_roo, dlrm_loss,
                                    dlrm_table_ids)
-    ed = m.embed_dim or 16
-    cfg = DLRMConfig(n_dense=4, embed_dim=ed, bot_mlp=(4, 32, ed),
-                     top_mlp=(64, 32, 1), vocabs=(512, 256, 64, 32),
-                     n_ro_fields=2, multi_hot=2)
-
-    def loss(p, b, r):
-        logits = dlrm_forward_roo(p, cfg, b["ro_dense"], b["ro_ids"],
-                                  b["ro_len"], b["nro_ids"], b["nro_len"],
-                                  b["seg"], plan=plan)
-        y = b["y"]
-        bce = (jnp.maximum(logits, 0) - logits * y
-               + jnp.log1p(jnp.exp(-jnp.abs(logits))))
-        return jnp.mean(bce)
-
+    cfg = dlrm_config(spec)
+    loss = lambda p, b, r: dlrm_loss(p, cfg, b, plan=plan)
     return ModelBundle(
         arch, cfg, dlrm_init(rng, cfg), loss,
-        sparse_vag(loss, lambda b: dlrm_table_ids(cfg, b["ro_ids"],
-                                                  b["nro_ids"])),
-        None, None)
+        sparse_vag(loss, lambda b: dlrm_table_ids(cfg, b)),
+        _ne_metrics(lambda p, b: (dlrm_logits_roo(p, cfg, b, plan=plan),
+                                  b.labels[:, 0], b.impression_mask())),
+        None)
 
 
-def synthetic_dlrm_batches(spec: ScenarioSpec, cfg, n_batches: int = 4
-                           ) -> List[Dict]:
-    """Deterministic field-dict batches for dlrm-mlperf (its MLPerf input
-    format predates the ROO schema; the stream simulator doesn't emit it)."""
-    import jax.numpy as jnp
-    import numpy as np
-
-    r = np.random.RandomState(spec.data.seed)
-    b_ro, b_nro = spec.batcher.b_ro, spec.batcher.b_nro
-    if b_nro % b_ro:
-        raise ScenarioValidationError(
-            f"scenario {spec.name!r}: dlrm synthetic batches need "
-            f"batcher.b_nro divisible by batcher.b_ro")
-    mh, n_ro = cfg.multi_hot, cfg.n_ro_fields
-    n_nro = cfg.n_sparse - n_ro
-    out = []
-    for _ in range(n_batches):
-        out.append({
-            "ro_dense": jnp.asarray(
-                r.normal(size=(b_ro, cfg.n_dense)).astype(np.float32)),
-            "ro_ids": jnp.asarray(np.stack(
-                [r.randint(0, cfg.vocabs[f], (b_ro, mh))
-                 for f in range(n_ro)], axis=1).astype(np.int32)),
-            "ro_len": jnp.full((b_ro, n_ro), mh, jnp.int32),
-            "nro_ids": jnp.asarray(np.stack(
-                [r.randint(0, cfg.vocabs[n_ro + f], (b_nro, mh))
-                 for f in range(n_nro)], axis=1).astype(np.int32)),
-            "nro_len": jnp.full((b_nro, n_nro), mh, jnp.int32),
-            "seg": jnp.repeat(jnp.arange(b_ro, dtype=jnp.int32),
-                              b_nro // b_ro),
-            "y": jnp.asarray(
-                (r.uniform(size=(b_nro,)) < 0.3).astype(np.float32))})
-    return out
+def dlrm_config(spec: ScenarioSpec):
+    """dlrm-mlperf at ``DLRMConfig``'s published widths, each table capped
+    at ``model.n_items`` rows."""
+    from repro.models.dlrm import DLRMConfig
+    return DLRMConfig(vocabs=tuple(min(v, spec.model.n_items)
+                                   for v in DLRMConfig.vocabs))
 
 
 # ---------------------------------------------------------------------------
@@ -451,19 +434,7 @@ def _train_from_scenario(spec: ScenarioSpec, *, ckpt_dir, shard_dir,
         lambda: bundle.params, plan=plan,
         value_and_grad_fn=bundle.vag_fn, metrics_fn=bundle.metrics_fn)
 
-    if spec.data.source == "synthetic" or arch == "dlrm-mlperf":
-        if arch != "dlrm-mlperf":
-            raise ScenarioValidationError(
-                f"data.source='synthetic' is the dlrm-mlperf field-batch "
-                f"path; {arch} trains from the event stream "
-                f"(data.source memory|disk)")
-        if spec.data.source != "synthetic":
-            raise ScenarioValidationError(
-                "dlrm-mlperf consumes MLPerf field-dict batches, not ROO "
-                "samples — set data.source='synthetic'")
-        batches = synthetic_dlrm_batches(spec, bundle.cfg)
-        state = trainer.run(_cycling_iter_fn(batches), rng)
-    elif spec.data.source == "disk":
+    if spec.data.source == "disk":
         state = _train_disk(spec, trainer, batcher_cfg, rng, plan,
                             shard_dir=shard_dir, ckpt_dir=ckpt_dir, log=log)
     else:
@@ -508,8 +479,8 @@ def _train_disk(spec, trainer, batcher_cfg, rng, plan, *, shard_dir,
         from repro.data.events import EventSimulator
         joiner = WatermarkJoiner(OnlineJoinConfig(
             label_wait_s=spec.data.label_wait_s))
-        samples = joiner.join(
-            EventSimulator(build_stream_cfg(spec)).stream())
+        samples = model_samples(spec, joiner.join(
+            EventSimulator(build_stream_cfg(spec)).stream()))
         manifest = write_samples(
             shard_dir, samples,
             requests_per_shard=spec.data.requests_per_shard,
@@ -560,8 +531,8 @@ def engine_from_scenario(spec: ScenarioSpec, params=None, rng_seed: int = 0,
     bundle = build_model(spec, jax.random.PRNGKey(rng_seed))
     if bundle.serve is None:
         raise ScenarioValidationError(
-            f"scenario {spec.name!r}: {spec.model.arch} is not servable "
-            f"through the ROO engine (field-dict batches, no ROO forward)")
+            f"scenario {spec.name!r}: {spec.model.arch} has no serving "
+            f"adapter for the ROO engine")
     sv = spec.serve
     policy = EnginePolicy(max_requests=sv.max_requests,
                           max_impressions=sv.max_impressions,

@@ -59,7 +59,6 @@ class ModelSpec:
     hist_len: int = 64
     seq_len: int = 0          # sequence models (dien/bert4rec); 0 = default
     m_targets: int = 16       # GR ranking targets
-    embed_dim: int = 0        # 0 = arch default
     variant: str = ""         # lsr mode / two-tower user-tower mode
 
 
@@ -75,7 +74,7 @@ class DataSpec:
     """Event stream + (for ``source="disk"``) the shard pipeline knobs.
     ``n_items=0`` follows ``model.n_items`` so the stream can never emit
     ids the model's tables don't cover."""
-    source: str = "memory"    # memory | disk | synthetic (dlrm field batches)
+    source: str = "memory"    # memory | disk
     n_requests: int = 800
     n_users: int = 200
     n_items: int = 0
@@ -299,9 +298,8 @@ class ScenarioSpec:
 
         if not self.model.arch:
             bad("model.arch is required")
-        if self.data.source not in ("memory", "disk", "synthetic"):
-            bad(f"data.source {self.data.source!r} not in "
-                f"memory|disk|synthetic")
+        if self.data.source not in ("memory", "disk"):
+            bad(f"data.source {self.data.source!r} not in memory|disk")
         for field, val in (("train.steps", self.train.steps),
                            ("train.log_every", self.train.log_every),
                            ("train.ckpt_every", self.train.ckpt_every),

@@ -295,41 +295,25 @@ class TestSparseTrajectoryParity:
             np.asarray(state_d["params"]["item_emb"]), rtol=1e-4, atol=1e-6)
 
     def test_dlrm_50_steps(self):
-        from repro.models.dlrm import (DLRMConfig, dlrm_forward_roo,
-                                       dlrm_init, dlrm_table_ids)
+        from repro.core.joiner import RequestLevelJoiner
+        from repro.data.batcher import BatcherConfig, ROOBatcher
+        from repro.data.events import EventSimulator, EventStreamConfig
+        from repro.models.dlrm import (DLRMConfig, dlrm_batcher_config,
+                                       dlrm_init, dlrm_loss, dlrm_sample,
+                                       dlrm_table_ids)
         cfg = DLRMConfig(n_dense=4, embed_dim=16, bot_mlp=(4, 32, 16),
                          top_mlp=(64, 32, 1), vocabs=(512, 256, 64, 32),
                          n_ro_fields=2, multi_hot=2)
         params = dlrm_init(jax.random.PRNGKey(0), cfg)
-        r = np.random.RandomState(0)
-        b_ro, b_nro = 8, 32
-        batches = []
-        for _ in range(4):
-            batches.append({
-                "ro_dense": jnp.asarray(
-                    r.normal(size=(b_ro, 4)).astype(np.float32)),
-                "ro_ids": jnp.asarray(
-                    r.randint(0, 512, (b_ro, 2, 2)).astype(np.int32)),
-                "ro_len": jnp.full((b_ro, 2), 2, jnp.int32),
-                "nro_ids": jnp.asarray(
-                    r.randint(0, 32, (b_nro, 2, 2)).astype(np.int32)),
-                "nro_len": jnp.full((b_nro, 2), 2, jnp.int32),
-                "seg": jnp.repeat(jnp.arange(b_ro, dtype=jnp.int32),
-                                  b_nro // b_ro),
-                "y": jnp.asarray(
-                    (r.uniform(size=(b_nro,)) < 0.3).astype(np.float32))})
-
-        def loss(p, b, r_):
-            logits = dlrm_forward_roo(p, cfg, b["ro_dense"], b["ro_ids"],
-                                      b["ro_len"], b["nro_ids"], b["nro_len"],
-                                      b["seg"])
-            y = b["y"]
-            bce = jnp.maximum(logits, 0) - logits * y + \
-                jnp.log1p(jnp.exp(-jnp.abs(logits)))
-            return jnp.mean(bce)
-
+        stream = EventStreamConfig(n_requests=60, n_items=512,
+                                   hist_init_max=12, seed=0)
+        samples = [dlrm_sample(s, cfg) for s in RequestLevelJoiner().join(
+            list(EventSimulator(stream).stream()))]
+        batches = list(ROOBatcher(dlrm_batcher_config(
+            cfg, BatcherConfig(b_ro=8, b_nro=32))).batches(samples))[:4]
+        loss = lambda p, b, r_: dlrm_loss(p, cfg, b)
         vag = make_sparse_value_and_grad(
-            loss, lambda b: dlrm_table_ids(cfg, b["ro_ids"], b["nro_ids"]))
+            loss, lambda b: dlrm_table_ids(cfg, b))
         losses_d, state_d = self._run(loss, params, batches, None,
                                       N_TRAJECTORY_STEPS)
         losses_s, state_s = self._run(loss, params, batches, vag,

@@ -163,3 +163,59 @@ def test_embedding_bag_forward_backward(one_chip, d, pooling):
     sh = one_chip
     _compile(fwd_bwd, _spec(sh, (4096, d)), _spec(sh, (256, 8), jnp.int32),
              _spec(sh, (256,), jnp.int32))
+
+
+def _dlrm_shapes(sharding, cfg, b_ro=8, per=5):
+    """A dlrm-mlperf training state and ROOBatch as shapes on ``sharding``:
+    ``b_ro`` requests of ``per`` impressions, one id a field."""
+    import numpy as np
+
+    from repro.core.joiner import ROOSample
+    from repro.data.batcher import BatcherConfig, ROOBatcher
+    from repro.models.dlrm import dlrm_batcher_config, dlrm_init
+    rng = np.random.default_rng(0)
+    reqs = [ROOSample(
+        request_id=i, user_id=i, ro_dense=rng.standard_normal(13),
+        ro_idlist=rng.integers(0, 3, 13).tolist(), history_ids=[],
+        history_actions=[], item_ids=[0] * per,
+        item_dense=[np.zeros((1,), np.float32)] * per,
+        item_idlist=[rng.integers(0, 3, 13).tolist() for _ in range(per)],
+        labels=[{"click": 1.0}] * per) for i in range(b_ro)]
+    batch = next(ROOBatcher(dlrm_batcher_config(cfg, BatcherConfig(
+        b_ro=b_ro, b_nro=b_ro * per))).batches(reqs))
+    params = jax.eval_shape(lambda: dlrm_init(jax.random.PRNGKey(0), cfg))
+    return jax.tree_util.tree_map(
+        lambda a: _spec(sharding, a.shape, a.dtype), (params, batch))
+
+
+def test_dlrm_step_runs_the_bag_kernels(one_chip):
+    """The dlrm-mlperf training step at the published widths (tables cut to
+    4,096 rows) runs one forward and one backward bag kernel per sparse
+    field, and ``emb_bag_roofline.train``'s pattern finds each of them and
+    nothing else."""
+    from repro.kernels import dispatch
+    from repro.models.dlrm import MLPERF_VOCABS, DLRMConfig, dlrm_loss
+    from repro.train.loop import make_train_step
+    from repro.train.optim import (adam, default_is_embedding, make_mixed,
+                                   rowwise_adagrad)
+    path = os.path.join(os.path.dirname(__file__), os.pardir, "chipbench",
+                        "metrics", "emb_bag_roofline.train.py")
+    spec = importlib.util.spec_from_file_location("emb_bag_roofline", path)
+    reader = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(reader)
+    cfg = DLRMConfig(vocabs=tuple(min(v, 4096) for v in MLPERF_VOCABS))
+    params, batch = _dlrm_shapes(one_chip, cfg)
+    opt = make_mixed(adam(1e-3), rowwise_adagrad(0.05), default_is_embedding)
+    state = {"params": params,
+             "opt": jax.tree_util.tree_map(
+                 lambda a: _spec(one_chip, a.shape, a.dtype),
+                 jax.eval_shape(opt.init, params)),
+             "step": _spec(one_chip, (), jnp.int32)}
+    key = _spec(one_chip, (2,), jnp.uint32)
+    step = make_train_step(lambda p, b, r: dlrm_loss(p, cfg, b), opt)
+    with dispatch.use_emb_backend("pallas"):
+        lines = _kernel_lines(step.lower(state, batch, key).compile())
+    assert len(lines) == 2 * cfg.n_sparse
+    assert all(re.search(reader.KERNELS, ln) for ln in lines), lines[0][:300]
+    for name in ("embedding_bag_fwd", "embedding_bag_bwd_rows"):
+        assert sum(f"%{name}." in ln for ln in lines) == cfg.n_sparse, name
