@@ -272,6 +272,14 @@ def _get_nested(tree, path):
     return tree
 
 
+def _replace_nested(tree: dict, path, value) -> dict:
+    """A copy of ``tree`` with the leaf at ``path`` replaced."""
+    out = dict(tree)
+    out[path[0]] = (value if len(path) == 1
+                    else _replace_nested(tree[path[0]], path[1:], value))
+    return out
+
+
 def _set_nested(tree: dict, path, value) -> None:
     for k in path[:-1]:
         tree = tree.setdefault(k, {})
@@ -326,6 +334,6 @@ def ef_compress_step(grads: Any, residual: Any, mode: str,
             STATS.record_exchange(
                 "grad:" + "/".join(path), g.shape, mode=mode, block=block,
                 kind="grad", collective="psum")
-        new_grads = _sp._set_path(new_grads, "/".join(path), sent)
-        new_res = _sp._set_path(new_res, "/".join(path), e2)
+        new_grads = _replace_nested(new_grads, path, sent)
+        new_res = _replace_nested(new_res, path, e2)
     return new_grads, new_res

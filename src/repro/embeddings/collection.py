@@ -15,12 +15,15 @@ HBM exactly once and duplicates expand from the small gathered buffer. The
 expansion is index bookkeeping only — outputs are bit-identical to the
 direct gather (tests/test_embeddings.py asserts exact equality).
 
-The same functions accept three table representations:
+The same functions accept four table representations:
 
   * a dense ``(V, D)`` array — the plain path;
-  * a :class:`repro.embeddings.sparse.GatheredTable` proxy — sparse-grad
-    training (``make_sparse_value_and_grad``): the batch's unique rows were
-    gathered up front, lookups translate ids by ``searchsorted``;
+  * a :class:`repro.embeddings.sparse.GatheredTable` proxy — touched-rows
+    training (the train step's default): the batch's unique rows were
+    gathered up front, lookups translate ids through its ``row_of`` map;
+  * a :class:`repro.embeddings.sparse.TableReads` stand-in — the train
+    step's recording pass: each lookup logs the ids it reads, then runs on
+    the plain table;
   * a dense array under an SPMD ``plan`` that row-shards it — routed through
     the explicit psum lookups of ``embeddings/sharded.py``. Dedup composes:
     the unique-id set is gathered through the psum path and expanded
@@ -45,7 +48,7 @@ import jax.numpy as jnp
 
 from repro.data.jagged import JaggedTensor, KeyedJagged
 from repro.embeddings.bag import bag_pool, bag_pool_dense
-from repro.embeddings.sparse import GatheredTable
+from repro.embeddings.sparse import GatheredTable, TableReads
 from repro.scenario.knobs import UNSET, Knob
 
 # tables this tall with this many ids per lookup dedup by default
@@ -176,6 +179,18 @@ def _plan_shards(table: Table, vocab: int, plan) -> bool:
     return table_is_sharded(plan, vocab)
 
 
+def _read(table: Table, vocab: int, plan, read_ids) -> Table:
+    """The table a lookup runs on: a :class:`TableReads` stand-in logs the
+    ids the lookup reads (``read_ids()``, in [0, vocab]: ``vocab`` for a
+    slot it skips) and hands its table on. A table the plan shards is read
+    whole: its rows are not local, so it keeps the dense gradient."""
+    if not isinstance(table, TableReads):
+        return table
+    if _plan_shards(table.table, vocab, plan):
+        return table.whole()
+    return table.read(read_ids())
+
+
 def _compress_active() -> bool:
     from repro.distributed import comms
     return comms.compress_mode() != "none"
@@ -189,6 +204,7 @@ def seq_lookup(table: Table, ids: jnp.ndarray, *, vocab: Optional[int] = None,
                plan=None, dedup: Optional[bool] = None) -> jnp.ndarray:
     """(B, L) ids -> (B, L, D); exact ``take(table, clip(ids))`` semantics."""
     v = _vocab_of(table, vocab)
+    table = _read(table, v, plan, lambda: jnp.clip(ids, 0, v - 1))
     if _plan_shards(table, v, plan):
         from repro.embeddings.sharded import sharded_seq_lookup
         clipped = jnp.clip(ids, 0, v - 1)
@@ -227,6 +243,8 @@ def bag_lookup(table: Table, ids: JaggedTensor, pooling: str = "sum", *,
     bag (already reduction-before-communication — dedup would only grow the
     collective); local/proxy tables dedup-gather then pool."""
     v = _vocab_of(table, None)
+    table = _read(table, v, plan, lambda: jnp.where(
+        ids.segment_ids() < ids.batch_size, jnp.clip(ids.values, 0, v - 1), v))
     if not isinstance(table, GatheredTable) and pooling in ("sum", "mean") \
             and _plan_shards(table, v, plan):
         from repro.embeddings.sharded import sharded_jagged_bag_lookup
@@ -244,10 +262,12 @@ def bag_lookup_dense(table: Table, ids: jnp.ndarray, lengths: jnp.ndarray,
                      out_sharded: Optional[bool] = None) -> jnp.ndarray:
     """Padded-layout bag: (B, L) ids + (B,) lengths -> (B, D).
 
-    On TPU (or under an explicit ``backend``) unsharded dense tables route
-    to the fused Pallas embedding-bag kernel (kernels/embedding_bag.py) —
-    unless dedup is forced (arg or "always" policy), which the per-slot DMA
-    kernel cannot honor. The jnp path dedup-gathers then pools. ``max``
+    On TPU (or under an explicit ``backend``) unsharded tables route to the
+    fused Pallas embedding-bag kernel (kernels/embedding_bag.py) — a
+    :class:`GatheredTable` as the kernel over its gathered rows, with each
+    id translated to its row there — unless dedup is forced (arg or
+    "always" policy), which the per-slot DMA kernel cannot honor. The jnp
+    path dedup-gathers then pools. ``max``
     pooling never routes to the psum bag (it cannot reassemble a max); on a
     plan-sharded table it falls back to the partitionable jnp gather.
 
@@ -258,6 +278,9 @@ def bag_lookup_dense(table: Table, ids: jnp.ndarray, lengths: jnp.ndarray,
     bytes of the psum). Numerically the same bag; only the layout differs.
     """
     v = _vocab_of(table, vocab)
+    table = _read(table, v, plan, lambda: jnp.where(
+        jnp.arange(ids.shape[1])[None, :] < lengths[:, None],
+        jnp.clip(ids, 0, v - 1), v))
     sharded = _plan_shards(table, v, plan)
     if pooling in ("sum", "mean") and sharded:
         from repro.embeddings.sharded import (sharded_bag_lookup,
@@ -277,12 +300,14 @@ def bag_lookup_dense(table: Table, ids: jnp.ndarray, lengths: jnp.ndarray,
                                   mesh=plan.mesh, vocab=v, pooling=pooling,
                                   model_axis=plan.model_axis,
                                   batch_axes=plan.batch_axes)
-    if not isinstance(table, GatheredTable) and not sharded \
-            and not _dedup_forced(dedup):
+    if not sharded and not _dedup_forced(dedup):
         from repro.kernels import dispatch
         be = dispatch.resolve_emb_backend(backend)
         if be != "jnp":
             from repro.kernels.embedding_bag import embedding_bag
+            if isinstance(table, GatheredTable):
+                return embedding_bag(table.rows, table.local(ids), lengths,
+                                     pooling, backend=be)
             return embedding_bag(table, ids, lengths, pooling, backend=be)
     emb = _gather(table, ids, v, dedup)
     return bag_pool_dense(emb, lengths, pooling)

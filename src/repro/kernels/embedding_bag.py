@@ -18,11 +18,10 @@ for mean; recomputed argmax indicator for max, done on the jnp side since
 it re-reads the gathered values), wraps them as
 ``embeddings.sparse.SparseRows`` with the slot ids as coordinates, and
 densifies only at the very end because the custom_vjp cotangent contract
-demands a (V, D) array for a (V, D) primal. (The end-to-end sparse
-TRAINING path never pays that densify: ``make_sparse_value_and_grad``
-differentiates w.r.t. gathered rows and bypasses this kernel's table
-cotangent entirely — ``embedding_bag_coo_grad`` is the seam to reuse the
-kernel backward in COO form should a fused-bag sparse path want it.)
+demands a (V, D) array for a (V, D) primal. (The train step's default
+touched-rows path runs this kernel over a batch's gathered rows, with ids
+translated to positions there, so that densify is (unique rows, D), never
+the table: ``embeddings/collection.bag_lookup_dense``.)
 
 Backend selection follows ``kernels/dispatch.py`` exactly like HSTU
 (explicit arg > ``use_emb_backend`` > ``set_default_emb_backend`` >

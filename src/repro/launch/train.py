@@ -85,10 +85,10 @@ def _parser() -> argparse.ArgumentParser:
                     help="embedding-bag backend (default: auto — fused "
                          "Pallas kernel on TPU, jnp elsewhere)")
     ap.add_argument("--sparse-emb", action="store_true",
-                    help="train embedding tables with COO row gradients + "
-                         "touched-rows-only row-wise Adagrad (recsys archs "
-                         "with a table_ids declaration; see "
-                         "docs/EMBEDDINGS.md)")
+                    help="take the tables' touched rows from the model's "
+                         "table_ids declaration instead of the train step's "
+                         "recording pass (recsys archs with a declaration; "
+                         "see docs/EMBEDDINGS.md)")
     ap.add_argument("--emb-dedup", default=None,
                     choices=("auto", "always", "never"),
                     help="request-level id dedup before embedding lookups "

@@ -79,13 +79,20 @@ def make_train_step(loss_fn: Callable, opt: Optimizer,
     leading microbatch axis; grads are accumulated (comm/compute overlap:
     the all-reduce happens once per step, not per microbatch).
 
-    ``value_and_grad_fn(params, batch, rng) -> (loss, grads)`` replaces the
-    default ``jax.value_and_grad(loss_fn)`` — the sparse-embedding path
-    (``embeddings.sparse.make_sparse_value_and_grad``) plugs in here, and
-    its ``SparseRows`` grad leaves flow through accumulation and into the
-    optimizer: the dense part rides the scan carry as before, the COO part
-    is emitted per-microbatch and stacked by the scan (a COO sum IS
-    concatenation; the optimizer's segment merge folds duplicates).
+    The default gradient is ``embeddings.sparse.touched_rows_value_and_grad``:
+    each table the optimizer updates row-wise (``opt.rows_mask``), of at
+    least ``SPARSE_MIN_VOCAB`` rows, that the loss reads only through the
+    embedding collection's lookups, at most ``TOUCHED_MAX_FILL`` ids a row
+    a step, gets its gradient as the rows the batch touched
+    (``SparseRows``), and the optimizer reads and writes only those rows;
+    every other leaf, and every leaf under an enabled ``plan``, gets its
+    dense gradient. ``value_and_grad_fn(params, batch, rng) -> (loss,
+    grads)`` replaces that default (``jax.value_and_grad(loss_fn)`` for an
+    all-dense step). ``SparseRows`` leaves flow through accumulation and
+    into the optimizer: the dense part rides the scan carry as before, the
+    COO part is emitted per-microbatch and stacked by the scan (a COO sum IS
+    concatenation; the step's segment merge folds duplicates). The metrics
+    count the distinct rows touched (``touched_rows``).
 
     With an enabled ``plan`` (distributed/sharding.py) and the matching
     ``state_shardings`` pytree (distributed/spmd.py), the step runs SPMD:
@@ -105,8 +112,8 @@ def make_train_step(loss_fn: Callable, opt: Optimizer,
     runs through error feedback (``ef_compress_step``) before the optimizer.
     """
     if value_and_grad_fn is None:
-        def value_and_grad_fn(params, b, r):
-            return jax.value_and_grad(loss_fn)(params, b, r)
+        value_and_grad_fn = _sp.touched_rows_value_and_grad(
+            loss_fn, opt.rows_mask, local=plan is None or not plan.enabled)
     vag = value_and_grad_fn
     comms_mode = _comms.compress_mode()
     comms_block = _comms.block_size()
@@ -173,30 +180,34 @@ def make_train_step(loss_fn: Callable, opt: Optimizer,
             grads, new_ef = _comms.ef_compress_step(
                 grads, state["comms_ef"], comms_mode, comms_block)
 
-        new_params, new_opt = opt.update(grads, state["opt"], params)
-        gnorm = jnp.sqrt(sum(_sp.sq_sum(g) for g in
-                             jax.tree.leaves(grads, is_leaf=_sp.is_sparse))
-                         + 1e-20)
+        # one merge per table: unique ids, so the norm below is exact and
+        # the optimizer's own merge is a no-op
+        grads = jax.tree.map(
+            lambda g: g.merged() if _sp.is_sparse(g) else g, grads,
+            is_leaf=_sp.is_sparse)
+        g_leaves = jax.tree.leaves(grads, is_leaf=_sp.is_sparse)
+        gnorm = jnp.sqrt(sum(_sp.sq_sum(g) for g in g_leaves) + 1e-20)
+        touched = sum((jnp.sum(g.ids < g.vocab, dtype=jnp.int32)
+                       for g in g_leaves if _sp.is_sparse(g)),
+                      jnp.zeros((), jnp.int32))
         # non-finite guard: a NaN/Inf loss or gradient must not poison the
-        # parameters — keep the old params/opt for this step (the step
-        # counter still advances so data alignment is unchanged) and
-        # surface the skip in metrics
+        # parameters — the optimizer keeps the old params/opt for this step
+        # (the step counter still advances so data alignment is unchanged)
+        # and the skip surfaces in metrics
         ok = jnp.isfinite(loss) & jnp.isfinite(gnorm)
-
-        def keep(new, old):
-            return jnp.where(ok, new, old)
-        new_params = jax.tree.map(keep, new_params, params)
-        new_opt = jax.tree.map(keep, new_opt, state["opt"])
+        new_params, new_opt = opt.update(grads, state["opt"], params, ok)
         # {**state, ...} carries pass-through keys (e.g. the base "rng")
         new_state = {**state, "params": new_params, "opt": new_opt,
                      "step": state["step"] + 1}
         if new_ef is not None:
             # the residual reverts with params on a skipped step — a
             # non-finite gradient must not poison the error accumulator
-            new_state["comms_ef"] = jax.tree.map(keep, new_ef,
-                                                 state["comms_ef"])
+            new_state["comms_ef"] = jax.tree.map(
+                lambda new, old: jnp.where(ok, new, old), new_ef,
+                state["comms_ef"])
         return new_state, {"loss": loss, "grad_norm": gnorm,
-                           "skipped": (~ok).astype(jnp.int32)}
+                           "skipped": (~ok).astype(jnp.int32),
+                           "touched_rows": touched}
 
     if plan is not None and plan.enabled and state_shardings is not None:
         # metrics sharding left to the compiler (None = unconstrained)

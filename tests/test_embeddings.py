@@ -1,6 +1,7 @@
 """The unified embedding subsystem: dedup lookups, the GatheredTable proxy,
-SparseRows gradients, the sparse row-wise Adagrad apply, and sparse-vs-dense
-training trajectory parity (LSR + DLRM).
+SparseRows gradients, the sparse row-wise Adagrad apply, sparse-vs-dense
+training trajectory parity (LSR + DLRM), and the train step's default
+touched-rows gradient against an explicit dense one (DLRM + hstu-gr).
 
 Dedup and proxy lookups are pure index bookkeeping, so the contracts here
 are EXACT equality (assert_array_equal); the optimizer sparse apply is
@@ -10,12 +11,15 @@ rtol 1e-5 over 50 steps (grad summation order differs between the paths).
 import jax
 import jax.numpy as jnp
 import numpy as np
+import pytest
 from hypothesis import given, settings, strategies as st
 
 from repro.data.jagged import JaggedTensor
 from repro.embeddings import collection as ec
-from repro.embeddings.sparse import (GatheredTable, SparseRows, gather_table,
-                                     make_sparse_value_and_grad)
+from repro.embeddings.sparse import (GatheredTable, SparseRows,
+                                     gather_table,
+                                     make_sparse_value_and_grad,
+                                     touched_rows_value_and_grad)
 from repro.train.optim import (adam, default_is_embedding, make_mixed,
                                rowwise_adagrad)
 
@@ -226,7 +230,7 @@ class TestSparseGradAccum:
                 losses.append(float(m["loss"]))
             return losses, state
 
-        losses_d, state_d = run(None)
+        losses_d, state_d = run(jax.value_and_grad(loss))
         losses_s, state_s = run(vag)
         # The two paths add the microbatches' row contributions in different
         # orders (dense accumulator vs COO segment-sum): the first step's
@@ -285,7 +289,8 @@ class TestSparseTrajectoryParity:
         loss = lambda p, b, r: lsr_loss(p, cfg, b)
         vag = make_sparse_value_and_grad(loss,
                                          lambda b: lsr_table_ids(cfg, b))
-        losses_d, state_d = self._run(loss, params, batches, None,
+        losses_d, state_d = self._run(loss, params, batches,
+                                      jax.value_and_grad(loss),
                                       N_TRAJECTORY_STEPS)
         losses_s, state_s = self._run(loss, params, batches, vag,
                                       N_TRAJECTORY_STEPS)
@@ -314,7 +319,8 @@ class TestSparseTrajectoryParity:
         loss = lambda p, b, r_: dlrm_loss(p, cfg, b)
         vag = make_sparse_value_and_grad(
             loss, lambda b: dlrm_table_ids(cfg, b))
-        losses_d, state_d = self._run(loss, params, batches, None,
+        losses_d, state_d = self._run(loss, params, batches,
+                                      jax.value_and_grad(loss),
                                       N_TRAJECTORY_STEPS)
         losses_s, state_s = self._run(loss, params, batches, vag,
                                       N_TRAJECTORY_STEPS)
@@ -324,3 +330,262 @@ class TestSparseTrajectoryParity:
                 np.asarray(state_s["params"]["tables"][name]),
                 np.asarray(tbl), rtol=1e-4, atol=1e-6,
                 err_msg=f"table {name} diverged")
+
+
+# ---------------------------------------------------------------------------
+# The train step's default: touched-rows gradients found by a recording pass
+# ---------------------------------------------------------------------------
+
+def _mixed():
+    return make_mixed(adam(1e-3), rowwise_adagrad(0.05), default_is_embedding)
+
+
+def _steps(loss, params, batches, vag=None, n=3, plan=None):
+    """``n`` steps of the real train step: (metrics per step, final state)."""
+    from repro.train.loop import make_train_step
+    opt = _mixed()
+    step = make_train_step(loss, opt, value_and_grad_fn=vag, plan=plan)
+    state = {"params": params, "opt": opt.init(params),
+             "step": jnp.zeros((), jnp.int32)}
+    metrics = []
+    for i in range(n):
+        state, m = step(state, batches[i % len(batches)],
+                        jax.random.PRNGKey(i))
+        metrics.append({k: int(v) if k == "touched_rows" else float(v)
+                        for k, v in m.items()})
+    return metrics, state
+
+
+def _tiny_dlrm(vocabs=(4096, 2048, 1024, 512, 32)):
+    """A tiny DLRM, its ROO batches, and the distinct valid ids each batch
+    reads, per table path. A step reads 16 id slots of each user-side table
+    (``t0``, ``t1``) and 64 of each item-side one: ``t2`` takes the touched
+    rows at 1/16 of its rows, ``t3`` reads too many for its 512 rows and
+    ``t4`` is below ``SPARSE_MIN_VOCAB``."""
+    from repro.core.joiner import RequestLevelJoiner
+    from repro.data.batcher import BatcherConfig, ROOBatcher
+    from repro.data.events import EventSimulator, EventStreamConfig
+    from repro.models.dlrm import (DLRMConfig, dlrm_batch_fields,
+                                   dlrm_batcher_config, dlrm_init, dlrm_loss,
+                                   dlrm_sample)
+    cfg = DLRMConfig(n_dense=4, embed_dim=16, bot_mlp=(4, 32, 16),
+                     top_mlp=(64, 32, 1), vocabs=vocabs, n_ro_fields=2,
+                     multi_hot=2)
+    stream = EventStreamConfig(n_requests=60, n_items=512, hist_init_max=12,
+                               seed=0)
+    samples = [dlrm_sample(s, cfg) for s in RequestLevelJoiner().join(
+        list(EventSimulator(stream).stream()))]
+    batches = list(ROOBatcher(dlrm_batcher_config(
+        cfg, BatcherConfig(b_ro=8, b_nro=32))).batches(samples))[:3]
+
+    def valid_ids(batch):
+        ro_ids, ro_len, nro_ids, nro_len = (
+            np.asarray(a) for a in dlrm_batch_fields(cfg, batch))
+        out = {}
+        for ids, lens, first in ((ro_ids, ro_len, 0),
+                                 (nro_ids, nro_len, cfg.n_ro_fields)):
+            for j in range(ids.shape[1]):
+                valid = np.arange(ids.shape[2])[None, :] < lens[:, j, None]
+                out[("tables", f"t{first + j}")] = set(
+                    ids[:, j][valid].tolist())
+        return out
+
+    return (lambda p, b, r: dlrm_loss(p, cfg, b),
+            dlrm_init(jax.random.PRNGKey(0), cfg), batches, valid_ids)
+
+
+def _tiny_gr():
+    """A tiny hstu-gr ranking model, its ROO batches, and the distinct ids
+    each batch's lookups read from ``item_emb`` (history slots and targets;
+    ``act_emb`` has 4 rows)."""
+    from repro.core.hstu import HSTUConfig
+    from repro.models.gr import GRConfig, gr_init, gr_ranking_loss
+    cfg = GRConfig(n_items=4096, hist_len=16, m_targets=8,
+                   hstu=HSTUConfig(d_model=32, n_heads=2, d_qk=16, d_v=16,
+                                   n_layers=1, max_rel_pos=24))
+
+    def valid_ids(batch):
+        ids = np.concatenate([
+            np.asarray(batch.history_ids)[:, :cfg.hist_len].ravel(),
+            np.asarray(batch.item_ids).ravel()])
+        return {("item_emb",): set(np.clip(ids, 0, cfg.n_items - 1).tolist())}
+
+    return (lambda p, b, r: gr_ranking_loss(p, cfg, b),
+            gr_init(jax.random.PRNGKey(0), cfg),
+            _roo_batches(n_items=cfg.n_items)[:3], valid_ids)
+
+
+def _at(tree, path):
+    for k in path:
+        tree = tree[k]
+    return tree
+
+
+def _touched(loss, params, batch):
+    """The param paths whose default gradient is a SparseRows."""
+    _, grads = jax.jit(touched_rows_value_and_grad(
+        loss, _mixed().rows_mask))(params, batch, jax.random.PRNGKey(0))
+    flat = jax.tree_util.tree_flatten_with_path(
+        grads, is_leaf=lambda g: isinstance(g, SparseRows))[0]
+    return {tuple(k.key for k in path) for path, g in flat
+            if isinstance(g, SparseRows)}
+
+
+MODELS = {"dlrm": _tiny_dlrm, "hstu-gr": _tiny_gr}
+
+
+class TestTouchedRowsDefault:
+    """The default step (a plain loss, no ``value_and_grad_fn``) against the
+    same step given an explicit dense ``jax.value_and_grad(loss)``."""
+
+    @pytest.mark.parametrize("model", sorted(MODELS))
+    def test_matches_the_dense_step(self, model):
+        loss, params, batches, _ = MODELS[model]()
+        m_t, s_t = _steps(loss, params, batches)
+        m_d, s_d = _steps(loss, params, batches, jax.value_and_grad(loss))
+        for a, b in zip(m_t, m_d):
+            np.testing.assert_allclose(a["loss"], b["loss"], rtol=1e-6)
+            np.testing.assert_allclose(a["grad_norm"], b["grad_norm"],
+                                       rtol=1e-5)
+        for key in ("params", "opt"):
+            for (path, a), (_, b) in zip(
+                    jax.tree_util.tree_flatten_with_path(s_t[key])[0],
+                    jax.tree_util.tree_flatten_with_path(s_d[key])[0]):
+                np.testing.assert_allclose(
+                    np.asarray(a), np.asarray(b), rtol=1e-5, atol=1e-7,
+                    err_msg=f"{key} diverged at {path}")
+
+    @pytest.mark.parametrize("model", sorted(MODELS))
+    def test_untouched_rows_stay_bit_identical(self, model):
+        loss, params, batches, valid_ids = MODELS[model]()
+        _, state = _steps(loss, params, batches)
+        paths = _touched(loss, params, batches[0])
+        touched = {}
+        for b in batches:
+            for path, ids in valid_ids(b).items():
+                if path in paths:
+                    touched.setdefault(path, set()).update(ids)
+        # row-wise Adagrad's accumulators, in the order make_mixed keeps them
+        names = [tuple(getattr(k, "key", None) for k in p) for p, _ in
+                 jax.tree_util.tree_flatten_with_path(params)[0]
+                 if default_is_embedding(tuple(str(k) for k in p))]
+        acc = dict(zip(names, state["opt"]["emb"]["acc"]))
+        checked = 0
+        for path, ids in touched.items():
+            start = np.asarray(_at(params, path))
+            rest = np.setdiff1d(np.arange(start.shape[0]), sorted(ids))
+            assert rest.size, path
+            np.testing.assert_array_equal(
+                np.asarray(_at(state["params"], path))[rest], start[rest])
+            np.testing.assert_array_equal(np.asarray(acc[path])[rest], 0.0)
+            moved = np.asarray(_at(state["params"], path)) != start
+            assert moved[sorted(ids)].any(), path
+            checked += 1
+        assert checked
+
+    @pytest.mark.parametrize("model", sorted(MODELS))
+    def test_touched_rows_counts_distinct_valid_ids(self, model):
+        loss, params, batches, valid_ids = MODELS[model]()
+        metrics, _ = _steps(loss, params, batches)
+        paths = _touched(loss, params, batches[0])
+        assert paths
+        for m, b in zip(metrics, batches):
+            want = sum(len(ids) for path, ids in valid_ids(b).items()
+                       if path in paths)
+            assert m["touched_rows"] == want
+        dense, _ = _steps(loss, params, batches, jax.value_and_grad(loss),
+                          n=1)
+        assert dense[0]["touched_rows"] == 0
+
+    def test_paths_are_counted_in_the_registry(self):
+        from repro.obs import metrics as obs_metrics
+        loss, params, batches, _ = _tiny_dlrm()
+        vag = touched_rows_value_and_grad(loss, _mixed().rows_mask)
+        with obs_metrics.OBS_KNOB.scoped("metrics"):
+            _, grads = jax.jit(vag)(params, batches[0], jax.random.PRNGKey(0))
+        gauge = obs_metrics.gauge("train.tables")
+        # t3 reads 64 id slots a step of 512 rows, over TOUCHED_MAX_FILL;
+        # t4 has 32 rows
+        assert gauge.value(path="touched_rows") == 3
+        assert gauge.value(path="dense") == 2
+        tables = grads["tables"]
+        assert all(isinstance(tables[t], SparseRows)
+                   for t in ("t0", "t1", "t2"))
+        assert not any(isinstance(tables[t], SparseRows)
+                       for t in ("t3", "t4"))
+
+    @pytest.mark.parametrize("read", ["matmul", "einsum", "jnp_take"])
+    def test_table_read_otherwise_stays_dense(self, read):
+        """A table the loss also reads outside the collection's lookups
+        keeps its dense gradient and still trains; the other table still
+        takes the touched rows. ``jnp_take`` is a use the recording stand-in
+        cannot follow: the tables are then recorded one at a time."""
+        out_read = {
+            "matmul": lambda h, t, ids: h @ t.T,
+            "einsum": lambda h, t, ids: jnp.einsum("bd,vd->bv", h, t),
+            "jnp_take": lambda h, t, ids: h @ jnp.take(t, ids, axis=0).T,
+        }[read]
+        params = {"emb": _rand_table(v=512, d=8, seed=1) * 0.1,
+                  "out_emb": _rand_table(v=80, d=8, seed=2) * 0.1}
+        ids = jax.random.randint(jax.random.PRNGKey(5), (6, 3), 0, 512)
+        batch = {"ids": ids, "lens": jnp.full((6,), 3, jnp.int32),
+                 "cand": jnp.arange(10)}
+
+        def loss(p, b, r):
+            h = ec.bag_lookup_dense(p["emb"], b["ids"], b["lens"])
+            return jnp.mean(jax.nn.log_softmax(
+                out_read(h, p["out_emb"], b["cand"]))[:, 0])
+
+        _, grads = jax.jit(touched_rows_value_and_grad(
+            loss, _mixed().rows_mask))(params, batch, jax.random.PRNGKey(0))
+        assert isinstance(grads["emb"], SparseRows)
+        assert not isinstance(grads["out_emb"], SparseRows)
+        m_t, s_t = _steps(loss, params, [batch])
+        m_d, s_d = _steps(loss, params, [batch], jax.value_and_grad(loss))
+        assert m_t[-1]["loss"] < m_t[0]["loss"]
+        assert [m["touched_rows"] for m in m_t] == [len(set(
+            np.asarray(ids).ravel().tolist()))] * 3
+        for a, b in zip(jax.tree.leaves(s_t), jax.tree.leaves(s_d)):
+            np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                       rtol=1e-5, atol=1e-7)
+
+    def test_enabled_plan_stays_dense(self):
+        """Under an enabled sharding plan every table keeps the dense
+        gradient (the touched-rows path gathers rows locally)."""
+        from repro.distributed.sharding import plan_for_mesh
+        from repro.launch.mesh import auto_mesh
+        from repro.obs import metrics as obs_metrics
+        loss, params, batches, _ = _tiny_dlrm()
+        plan = plan_for_mesh(auto_mesh((1, 1), ("data", "model")))
+        assert plan.enabled
+        with obs_metrics.OBS_KNOB.scoped("metrics"):
+            m_p, s_p = _steps(loss, params, batches, plan=plan)
+        gauge = obs_metrics.gauge("train.tables")
+        assert gauge.value(path="touched_rows") == 0
+        assert gauge.value(path="dense") == 5
+        assert [m["touched_rows"] for m in m_p] == [0, 0, 0]
+        _, s_d = _steps(loss, params, batches, jax.value_and_grad(loss))
+        for a, b in zip(jax.tree.leaves(s_p), jax.tree.leaves(s_d)):
+            np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+    def test_no_table_shaped_broadcast(self):
+        """The default step of a tiny DLRM holds no dense gradient of its
+        4,099-row table: no broadcast (the zero fill of a dense table
+        gradient) to the table's shape, in the lowered program or the
+        compiled one. The explicit dense step has them."""
+        import re
+        from repro.train.loop import make_train_step
+        loss, params, batches, _ = _tiny_dlrm(vocabs=(4099, 256, 64, 32, 16))
+        opt = _mixed()
+        state = {"params": params, "opt": opt.init(params),
+                 "step": jnp.zeros((), jnp.int32)}
+
+        def broadcasts(vag):
+            low = make_train_step(loss, opt, value_and_grad_fn=vag).lower(
+                state, batches[0], jax.random.PRNGKey(0))
+            return (len(re.findall(r"broadcast_in_dim.*-> tensor<4099x16xf32>",
+                                   low.as_text())),
+                    len(re.findall(r"f32\[4099,16\]\{[^}]*\} broadcast\(",
+                                   low.compile().as_text())))
+        assert broadcasts(None) == (0, 0)
+        assert min(broadcasts(jax.value_and_grad(loss))) > 0

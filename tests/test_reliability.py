@@ -35,7 +35,7 @@ from repro.reliability import (ENV_VAR, FaultPlan, FaultSpec, InjectedFault,
 from repro.serve.engine import EnginePolicy, ScoreError, ScoringEngine
 from repro.train.checkpoint import CheckpointCorruptionError, CheckpointManager
 from repro.train.loop import (NonFiniteLossError, Trainer, TrainLoopConfig,
-                              make_train_step)
+                              _poison_batch, make_train_step)
 from repro.train.optim import sgd
 
 
@@ -425,30 +425,75 @@ def _toy_init():
     return {"w": jnp.ones((4,), jnp.float32)}
 
 
+def _table_loss(params, batch, rng):
+    from repro.embeddings import collection as ec
+    e = ec.seq_lookup(params["item_emb"], batch["ids"])
+    return jnp.mean((jnp.sum(e, -1) * params["w"] - batch["y"]) ** 2)
+
+
+def _table_init():
+    return {"item_emb": jnp.linspace(-1.0, 1.0, 256 * 8).reshape(256, 8),
+            "w": jnp.ones((4,), jnp.float32)}
+
+
+def _table_batches(start):
+    for step in range(start, 10_000):
+        yield {"y": jnp.full((3, 4), 1.0 + 0.1 * step, jnp.float32),
+               "ids": (jnp.arange(12, dtype=jnp.int32).reshape(3, 4)
+                       * 7 + step) % 256}
+
+
+def _mixed_opt():
+    from repro.train.optim import (adam, default_is_embedding, make_mixed,
+                                   rowwise_adagrad)
+    return make_mixed(adam(0.01), rowwise_adagrad(0.1), default_is_embedding)
+
+
+# toy: one dense leaf under SGD; tables: a 256-row table that the default
+# step updates through its touched rows, beside an Adam leaf
+GUARD_CASES = {
+    "toy": (_toy_loss, lambda: sgd(lr=0.1), _toy_init, _toy_batches),
+    "tables": (_table_loss, _mixed_opt, _table_init, _table_batches),
+}
+
+
 class TestTrainerGuard:
-    def test_nan_batches_skipped_params_unpoisoned(self):
+    @pytest.mark.parametrize("case", sorted(GUARD_CASES))
+    def test_nan_batches_skipped_params_unpoisoned(self, case):
+        loss, make_opt, init, batches_from = GUARD_CASES[case]
         rng = jax.random.PRNGKey(0)
         cfg = TrainLoopConfig(total_steps=6, log_every=100,
                               halt_after_skips=10)
         plan = FaultPlan([FaultSpec("train.batch", "nan", max_fires=2)])
         with use_plan(plan):
-            tr = Trainer(_toy_loss, sgd(lr=0.1), cfg, _toy_init)
-            state = tr.run(lambda s: _toy_batches(s), rng)
+            tr = Trainer(loss, make_opt(), cfg, init)
+            state = tr.run(lambda s: batches_from(s), rng)
         assert tr.skipped_steps == 2
-        w = np.asarray(state["params"]["w"])
-        assert np.isfinite(w).all()
+        for leaf in jax.tree.leaves(state["params"]):
+            assert np.isfinite(np.asarray(leaf)).all()
         # reference: steps 0 and 1 were frozen, so the final params equal
         # applying only steps 2..5 (same batches, same fold_in keys)
-        opt = sgd(lr=0.1)
-        step_fn = make_train_step(_toy_loss, opt)
-        params = _toy_init()
+        opt = make_opt()
+        step_fn = make_train_step(loss, opt)
+        params = init()
         ref = {"params": params, "opt": opt.init(params),
                "step": jnp.zeros((), jnp.int32), "rng": rng}
-        batches = list(b for _, b in zip(range(6), _toy_batches(0)))
+        batches = list(b for _, b in zip(range(6), batches_from(0)))
+        # a NaN batch leaves every leaf, table and accumulator
+        # included, bit for bit as it was, and reports the skip
+        skipped, met = step_fn(ref, _poison_batch(batches[0]), rng)
+        assert int(met["skipped"]) == 1
+        for a, b in zip(jax.tree.leaves(skipped["params"])
+                        + jax.tree.leaves(skipped["opt"]),
+                        jax.tree.leaves(ref["params"])
+                        + jax.tree.leaves(ref["opt"])):
+            np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
         for step in range(2, 6):
             ref, _ = step_fn(ref, batches[step],
                              jax.random.fold_in(rng, step))
-        np.testing.assert_array_equal(w, np.asarray(ref["params"]["w"]))
+        for a, b in zip(jax.tree.leaves(state["params"]),
+                        jax.tree.leaves(ref["params"])):
+            np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
 
     def test_consecutive_skips_halt(self):
         cfg = TrainLoopConfig(total_steps=50, log_every=100,

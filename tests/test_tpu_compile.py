@@ -188,6 +188,14 @@ def _dlrm_shapes(sharding, cfg, b_ro=8, per=5):
         lambda a: _spec(sharding, a.shape, a.dtype), (params, batch))
 
 
+def _train_state(sharding, params, opt):
+    return {"params": params,
+            "opt": jax.tree_util.tree_map(
+                lambda a: _spec(sharding, a.shape, a.dtype),
+                jax.eval_shape(opt.init, params)),
+            "step": _spec(sharding, (), jnp.int32)}
+
+
 def test_dlrm_step_runs_the_bag_kernels(one_chip):
     """The dlrm-mlperf training step at the published widths (tables cut to
     4,096 rows) runs one forward and one backward bag kernel per sparse
@@ -206,11 +214,7 @@ def test_dlrm_step_runs_the_bag_kernels(one_chip):
     cfg = DLRMConfig(vocabs=tuple(min(v, 4096) for v in MLPERF_VOCABS))
     params, batch = _dlrm_shapes(one_chip, cfg)
     opt = make_mixed(adam(1e-3), rowwise_adagrad(0.05), default_is_embedding)
-    state = {"params": params,
-             "opt": jax.tree_util.tree_map(
-                 lambda a: _spec(one_chip, a.shape, a.dtype),
-                 jax.eval_shape(opt.init, params)),
-             "step": _spec(one_chip, (), jnp.int32)}
+    state = _train_state(one_chip, params, opt)
     key = _spec(one_chip, (2,), jnp.uint32)
     step = make_train_step(lambda p, b, r: dlrm_loss(p, cfg, b), opt)
     with dispatch.use_emb_backend("pallas"):
@@ -219,3 +223,60 @@ def test_dlrm_step_runs_the_bag_kernels(one_chip):
     assert all(re.search(reader.KERNELS, ln) for ln in lines), lines[0][:300]
     for name in ("embedding_bag_fwd", "embedding_bag_bwd_rows"):
         assert sum(f"%{name}." in ln for ln in lines) == cfg.n_sparse, name
+
+
+def test_dlrm_step_has_no_dense_table_gradient(one_chip):
+    """The default dlrm-mlperf step (tables cut to a distinctive 4,099 rows)
+    updates its tables through the rows a step touches: no broadcast to a
+    table's shape is left, the zero fill of a dense table gradient. The
+    same step given an explicit dense gradient has them."""
+    from repro.models.dlrm import MLPERF_VOCABS, DLRMConfig, dlrm_loss
+    from repro.train.loop import make_train_step
+    from repro.train.optim import (adam, default_is_embedding, make_mixed,
+                                   rowwise_adagrad)
+    cfg = DLRMConfig(vocabs=tuple(min(v, 4099) for v in MLPERF_VOCABS))
+    params, batch = _dlrm_shapes(one_chip, cfg)
+    opt = make_mixed(adam(1e-3), rowwise_adagrad(0.05), default_is_embedding)
+    state = _train_state(one_chip, params, opt)
+    key = _spec(one_chip, (2,), jnp.uint32)
+
+    def loss(p, b, r):
+        return dlrm_loss(p, cfg, b)
+
+    def table_broadcasts(vag):
+        step = make_train_step(loss, opt, value_and_grad_fn=vag)
+        text = step.lower(state, batch, key).compile().as_text()
+        return len(re.findall(r"f32\[4099,128\]\{[^}]*\} broadcast\(", text))
+    assert table_broadcasts(None) == 0
+    assert table_broadcasts(jax.value_and_grad(loss)) > 0
+
+
+def test_gr_step_runs_each_hstu_kernel_once(one_chip, roo_samples):
+    """The default step's recording pass (a second, unused forward that
+    finds the tables' ids) is compiled away: the hstu-gr step runs the
+    forward kernel and the two backward kernels once each."""
+    from repro.core.hstu import HSTUConfig
+    from repro.data.batcher import BatcherConfig, ROOBatcher
+    from repro.kernels import dispatch
+    from repro.models.gr import GRConfig, gr_init, gr_ranking_loss
+    from repro.train.loop import make_train_step
+    from repro.train.optim import (adam, default_is_embedding, make_mixed,
+                                   rowwise_adagrad)
+    cfg = GRConfig(n_items=4096, hist_len=256, m_targets=8,
+                   hstu=HSTUConfig(d_model=128, n_heads=2, d_qk=64, d_v=64,
+                                   n_layers=1, max_rel_pos=64))
+    batch = next(ROOBatcher(BatcherConfig(
+        b_ro=4, b_nro=16, hist_len=cfg.hist_len)).batches(roo_samples))
+    params = jax.eval_shape(lambda: gr_init(jax.random.PRNGKey(0), cfg))
+    params, batch = jax.tree_util.tree_map(
+        lambda a: _spec(one_chip, a.shape, a.dtype), (params, batch))
+    opt = make_mixed(adam(1e-3), rowwise_adagrad(0.05), default_is_embedding)
+    step = make_train_step(lambda p, b, r: gr_ranking_loss(p, cfg, b), opt)
+    with dispatch.use_backend("pallas"):
+        lines = _kernel_lines(step.lower(
+            _train_state(one_chip, params, opt), batch,
+            _spec(one_chip, (2,), jnp.uint32)).compile())
+    assert len(lines) == 3
+    for name in ("hstu_attn_fwd", "hstu_attn_bwd_dq", "hstu_attn_bwd_dkv"):
+        assert sum(bool(re.match(rf"%\w*{name}_*\.\d+ = ", ln))
+                   for ln in lines) == 1, name
