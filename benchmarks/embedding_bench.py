@@ -12,8 +12,9 @@ Emits the standard ``name,us_per_call,derived`` rows:
                              by the unique count)
   embedding_grads_dense    — value_and_grad of a pooled-bag loss w.r.t. the
                              full (V, D) table (dense scatter backward)
-  embedding_grads_sparse   — make_sparse_value_and_grad: dedup gather +
-                             COO SparseRows backward (touched rows only)
+  embedding_grads_sparse   — touched_rows_value_and_grad (the train step's
+                             default): dedup gather + COO SparseRows
+                             backward (touched rows only)
   embedding_step_dense     — grads + dense row-wise Adagrad (reads/writes
                              all V rows)
   embedding_step_sparse    — COO grads + touched-rows-only sparse apply
@@ -40,7 +41,7 @@ import numpy as np
 from benchmarks.common import emit, time_fn
 from repro.embeddings import collection as ec
 from repro.embeddings.collection import dedup_gather
-from repro.embeddings.sparse import make_sparse_value_and_grad
+from repro.embeddings.sparse import touched_rows_value_and_grad
 from repro.train.optim import rowwise_adagrad
 
 ZIPF_ALPHA = 1.1
@@ -79,9 +80,9 @@ def run(smoke: bool = False) -> None:
                                 dedup=False)
         return jnp.sum(e ** 2)
 
-    vag_sparse = make_sparse_value_and_grad(loss, lambda b_: {"t": b_["ids"]})
-    vag_dense = lambda p, b_, r: jax.value_and_grad(loss)(p, b_, r)
     opt = rowwise_adagrad(0.05)
+    vag_sparse = touched_rows_value_and_grad(loss, opt.rows_mask)
+    vag_dense = lambda p, b_, r: jax.value_and_grad(loss)(p, b_, r)
     params = {"t": table}
     state = opt.init(params)
     batch = {"ids": ids, "lens": lengths}

@@ -6,6 +6,8 @@ from __future__ import annotations
 import importlib
 from typing import List, Mapping, Optional
 
+from repro.scenario.archs import ARCHS
+
 _MODULES = {
     "starcoder2-15b": "repro.configs.starcoder2_15b",
     "deepseek-coder-33b": "repro.configs.deepseek_coder_33b",
@@ -47,36 +49,21 @@ def all_cells() -> List[tuple]:
 # Declarative scenarios (the recsys zoo as ScenarioSpecs)
 # ---------------------------------------------------------------------------
 
-# every trainable recsys arch; the factory defaults reproduce what
-# `launch/train.py --arch <id>` did before specs existed, so existing
-# invocations and CI commands behave identically
-SCENARIO_ARCHS = ("roo-lsr", "roo-esr", "roo-retrieval", "hstu-gr",
-                  "dien", "mind", "bert4rec", "dlrm-mlperf")
+# every trainable recsys arch: the keys of scenario/archs.py's table
+SCENARIO_ARCHS = tuple(ARCHS)
 
 
 def scenario(arch_id: str, overrides: Optional[Mapping] = None):
-    """The registered ScenarioSpec for ``arch_id``, optionally with dotted
-    ``--set``-style overrides (e.g. ``{"train.steps": 20}``) applied."""
-    from repro.scenario.spec import (BatcherSpec, DataSpec, ModelSpec,
-                                     ScenarioSpec)
-    if arch_id not in SCENARIO_ARCHS:
+    """The registered ScenarioSpec for ``arch_id`` (its table entry's
+    defaults), optionally with dotted ``--set``-style overrides (e.g.
+    ``{"train.steps": 20}``) applied."""
+    from repro.scenario.spec import ModelSpec, ScenarioSpec
+    if arch_id not in ARCHS:
         raise KeyError(f"no registered scenario {arch_id!r}; "
                        f"known: {SCENARIO_ARCHS}")
-    model = ModelSpec(arch=arch_id)
-    batcher = BatcherSpec()
-    data = DataSpec(hist_init_max=48, n_requests=800)
-    if arch_id == "bert4rec":
-        model = ModelSpec(arch=arch_id, seq_len=65)
-    elif arch_id == "dien":
-        model = ModelSpec(arch=arch_id, seq_len=64)
-    elif arch_id == "dlrm-mlperf":
-        # DLRMConfig's published widths; n_items caps every table's rows
-        # (and sizes the stream), so the CPU smoke run holds the tables
-        model = ModelSpec(arch=arch_id, n_items=1000)
-        batcher = BatcherSpec(b_ro=8, b_nro=32)
-    spec = ScenarioSpec(name=arch_id, model=model, batcher=batcher,
-                        data=data).validate()
-    return spec.with_overrides(overrides) if overrides else spec
+    spec = ScenarioSpec(name=arch_id, model=ModelSpec(arch=arch_id))
+    return spec.with_overrides({**ARCHS[arch_id].defaults,
+                                **(overrides or {})})
 
 
 def all_scenarios() -> List:

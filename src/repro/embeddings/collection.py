@@ -361,18 +361,3 @@ class EmbeddingCollection:
         return {name: self.lookup(tables, name, kj[name], plan=plan,
                                   dedup=dedup)
                 for name in kj.keys() if name in self.features}
-
-    def request_ids(self, feature_ids: Dict[str, jnp.ndarray],
-                    prefix: str = "") -> Dict[str, jnp.ndarray]:
-        """Fold per-feature id arrays into per-table flat id sets — the
-        ``table_ids_fn`` payload ``make_sparse_value_and_grad`` wants.
-        ``prefix`` locates the tables dict inside the params tree
-        (e.g. "tables/")."""
-        by_table: Dict[str, list] = {}
-        for name, ids in feature_ids.items():
-            f = self.features[name]
-            flat = (ids.values if isinstance(ids, JaggedTensor)
-                    else ids).reshape(-1)
-            by_table.setdefault(f.table, []).append(flat)
-        return {f"{prefix}{t}": jnp.concatenate(parts)
-                for t, parts in by_table.items()}

@@ -22,8 +22,7 @@ end; it is the train step's default (``train/loop.make_train_step``):
     finds the tables and their ids by a recording pass, then differentiates
     through gathered rows. The returned grads tree carries
     :class:`SparseRows` at every such table and plain dense arrays
-    everywhere else. :func:`make_sparse_value_and_grad` does the same from
-    a model's own per-table id declaration.
+    everywhere else.
 
 Why a proxy instead of a ``custom_vjp`` that returns ``SparseRows`` for a
 dense table argument: JAX requires a cotangent structurally identical to the
@@ -98,9 +97,6 @@ class SparseRows:
         the dense cotangent of kernels/embedding_bag.py."""
         out = jnp.zeros(self.shape, self.rows.dtype)
         return out.at[self.ids].add(self.rows, mode="drop")
-
-    def scale(self, s) -> "SparseRows":
-        return SparseRows(self.ids, self.rows * s, self.vocab, self.unique)
 
 
 def is_sparse(x) -> bool:
@@ -191,9 +187,7 @@ class GatheredTable:
     maps each id of the table to its row in ``rows`` (:meth:`local`), one
     gather per lookup. Through :meth:`take`, ids absent from ``uids`` read
     as zero rows — structurally impossible when the gathered ids cover the
-    loss's lookups (the recording pass guarantees it; a model's
-    ``table_ids`` declaration must), and loudly wrong in the sparse-vs-dense
-    parity tests when they don't.
+    loss's lookups, which the recording pass guarantees.
     """
 
     uids: jnp.ndarray    # (N,) int32 sorted; vocab == padding
@@ -414,33 +408,3 @@ def touched_rows_value_and_grad(loss_fn: Callable,
 
     return vag
 
-
-def make_sparse_value_and_grad(loss_fn: Callable,
-                               table_ids_fn: Callable,
-                               min_vocab: int = SPARSE_MIN_VOCAB) -> Callable:
-    """Sparse-gradient ``value_and_grad`` from a model's own declaration.
-
-    ``loss_fn(params, batch, rng) -> scalar`` must route every lookup of the
-    declared tables through ``embeddings/collection.py`` (which accepts the
-    :class:`GatheredTable` proxy). ``table_ids_fn(batch) -> {path: ids}``
-    declares, per table (a ``/``-joined params path), every id the forward
-    will look up — models export these next to their losses
-    (``lsr_table_ids``, ``dlrm_table_ids``, ...). Declared tables below
-    ``min_vocab`` rows keep the plain dense gradient path.
-
-    Returns ``vag(params, batch, rng) -> (loss, grads)`` where ``grads`` has
-    a :class:`SparseRows` at each declared table path and plain dense arrays
-    elsewhere; drop it into ``make_train_step(value_and_grad_fn=...)``.
-    """
-    def vag(params, batch, rng):
-        flat = jax.tree_util.tree_flatten_with_path(params)[0]
-        at = {jax.tree_util.keystr(path, simple=True, separator="/"): i
-              for i, (path, _) in enumerate(flat)}
-        ids_at = {}
-        for p, ids in table_ids_fn(batch).items():
-            vocab = flat[at[p]][1].shape[0]
-            if vocab >= min_vocab:
-                ids_at[at[p]] = jnp.clip(ids, 0, vocab - 1)
-        return _value_and_grad_rows(loss_fn, params, batch, rng, ids_at)
-
-    return vag

@@ -30,13 +30,13 @@ def _kernel(tril_ref, t_ref, o_ref, *, d: int):
     o_ref[...] = jnp.concatenate([dense, pairs], axis=1).astype(o_ref.dtype)
 
 
-def dot_interaction(dense_out: jnp.ndarray, sparse_embs: jnp.ndarray,
+def dot_interaction(dense_out: jnp.ndarray, embs: jnp.ndarray,
                     block_b: int = 128, interpret: bool = True) -> jnp.ndarray:
-    """dense_out: (B, D); sparse_embs: (B, F, D) -> (B, D + (F+1)F/2)."""
+    """dense_out: (B, D); embs: (B, F, D) -> (B, D + (F+1)F/2)."""
     b, d = dense_out.shape
-    f = sparse_embs.shape[1]
+    f = embs.shape[1]
     f1 = f + 1
-    t = jnp.concatenate([dense_out[:, None, :], sparse_embs], axis=1)
+    t = jnp.concatenate([dense_out[:, None, :], embs], axis=1)
     bb = min(block_b, b)
     assert b % bb == 0, (b, bb)
     i, j = np.tril_indices(f1, k=-1)

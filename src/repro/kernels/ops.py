@@ -36,11 +36,11 @@ def embedding_bag(table, ids, lengths, *, pooling: str = "sum",
 
 
 @partial(jax.jit, static_argnames=("backend",))
-def dot_interaction(dense_out, sparse_embs, *,
+def dot_interaction(dense_out, embs, *,
                     backend: Optional[str] = None):
     """DLRM dot interaction on the embedding-kernel backend family."""
     be = _dispatch.resolve_emb_backend(backend)
     if be == "jnp":
-        return _ref.dot_interaction_ref(dense_out, sparse_embs)
-    return _dot_pallas(dense_out, sparse_embs,
+        return _ref.dot_interaction_ref(dense_out, embs)
+    return _dot_pallas(dense_out, embs,
                        interpret=(be == "pallas-interpret"))

@@ -113,10 +113,10 @@ def embedding_bag_ref(table: jnp.ndarray, ids: jnp.ndarray,
 
 
 def dot_interaction_ref(dense_out: jnp.ndarray,
-                        sparse_embs: jnp.ndarray) -> jnp.ndarray:
-    """DLRM dot interaction. dense_out: (B, D); sparse_embs: (B, F, D).
+                        embs: jnp.ndarray) -> jnp.ndarray:
+    """DLRM dot interaction. dense_out: (B, D); embs: (B, F, D).
     Returns (B, D + (F+1)F/2) — dense concat strict-lower-tri pairwise dots."""
-    t = jnp.concatenate([dense_out[:, None, :], sparse_embs], axis=1)
+    t = jnp.concatenate([dense_out[:, None, :], embs], axis=1)
     z = jnp.einsum("bfd,bgd->bfg", t, t, preferred_element_type=jnp.float32)
     f = t.shape[1]
     i, j = jnp.tril_indices(f, k=-1)

@@ -84,11 +84,6 @@ def _parser() -> argparse.ArgumentParser:
                     choices=("pallas", "pallas-interpret", "jnp"),
                     help="embedding-bag backend (default: auto — fused "
                          "Pallas kernel on TPU, jnp elsewhere)")
-    ap.add_argument("--sparse-emb", action="store_true",
-                    help="take the tables' touched rows from the model's "
-                         "table_ids declaration instead of the train step's "
-                         "recording pass (recsys archs with a declaration; "
-                         "see docs/EMBEDDINGS.md)")
     ap.add_argument("--emb-dedup", default=None,
                     choices=("auto", "always", "never"),
                     help="request-level id dedup before embedding lookups "
@@ -172,8 +167,6 @@ def _flag_overrides(args) -> dict:
     out = {k: v for k, v in mapping.items() if v is not None}
     if args.obs_export:
         out["obs.export"] = True
-    if args.sparse_emb:
-        out["train.sparse_emb"] = True
     if args.strict_shards:
         out["data.strict_shards"] = True
     if args.no_prefetch:

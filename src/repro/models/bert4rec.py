@@ -10,8 +10,8 @@ encoded representation at the mask position. Encoder-only: no decode shapes.
 
 Embedding path: lookups route through embeddings/collection.py (dedup'd
 gathers), but the cloze head's full softmax (``enc @ item_emb.T``) reads
-every table row, so BERT4Rec trains with dense embedding gradients — it is
-the one model without a ``table_ids`` declaration for the sparse path.
+every table row, so BERT4Rec trains with dense embedding gradients (the
+train step's recording pass sees the matmul and keeps the table dense).
 """
 from __future__ import annotations
 

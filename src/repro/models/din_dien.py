@@ -137,13 +137,6 @@ def dien_logits_roo(params: Dict, cfg: DIENConfig, batch: ROOBatch) -> jnp.ndarr
     return mlp_apply(params["out_mlp"], x)[:, 0]
 
 
-def dien_table_ids(cfg: DIENConfig, batch: ROOBatch) -> Dict:
-    """Per-table id declaration for sparse-gradient training."""
-    return {"item_emb": jnp.concatenate([
-        batch.history_ids[:, :cfg.seq_len].reshape(-1),
-        batch.item_ids.reshape(-1)])}
-
-
 def dien_loss(params: Dict, cfg: DIENConfig, batch: ROOBatch) -> jnp.ndarray:
     logits = dien_logits_roo(params, cfg, batch)
     y = batch.labels[:, 0]

@@ -281,19 +281,6 @@ def dlrm_loss(params: Dict, cfg: DLRMConfig, batch: ROOBatch,
     return jnp.sum(bce * w) / jnp.maximum(jnp.sum(w), 1.0)
 
 
-def dlrm_table_ids(cfg: DLRMConfig, batch: ROOBatch) -> Dict[str, jnp.ndarray]:
-    """Per-table flat id sets of one ROO batch (params-tree paths), for
-    ``embeddings.sparse.make_sparse_value_and_grad`` — folded through the
-    collection's feature routing so declaration and lookup cannot drift."""
-    ro_ids, _, nro_ids, _ = dlrm_batch_fields(cfg, batch)
-    feats = {}
-    for j, f in enumerate(range(cfg.n_ro_fields)):
-        feats[f"f{f}"] = ro_ids[:, j]
-    for j, f in enumerate(range(cfg.n_ro_fields, cfg.n_sparse)):
-        feats[f"f{f}"] = nro_ids[:, j]
-    return cfg.collection().request_ids(feats, prefix="tables/")
-
-
 def dlrm_flops_per_example(cfg: DLRMConfig) -> int:
     """Analytic dense forward FLOPs per impression (impression-level)."""
     from repro.models.mlp import mlp_flops

@@ -184,16 +184,6 @@ def gr_extend_user_state(params: Dict, cfg: GRConfig, batch: ROOBatch,
     return GRUserState(ks, vs, prefix + new_counts)
 
 
-def gr_table_ids(cfg: GRConfig, batch: ROOBatch) -> Dict:
-    """Per-table id declaration for sparse-gradient training (ranking
-    path; retrieval adds the shifted next-item targets, already covered by
-    the history slice)."""
-    return {"item_emb": jnp.concatenate([
-                batch.history_ids[:, :cfg.hist_len].reshape(-1),
-                batch.item_ids.reshape(-1)]),
-            "act_emb": batch.history_actions[:, :cfg.hist_len].reshape(-1)}
-
-
 def gr_ranking_loss(params: Dict, cfg: GRConfig, batch: ROOBatch,
                     plan=None) -> jnp.ndarray:
     logits = gr_ranking_logits(params, cfg, batch, plan=plan)

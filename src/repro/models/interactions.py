@@ -13,14 +13,14 @@ import jax
 import jax.numpy as jnp
 
 
-def dot_interaction(dense_out: jnp.ndarray, sparse_embs: jnp.ndarray,
+def dot_interaction(dense_out: jnp.ndarray, embs: jnp.ndarray,
                     self_interaction: bool = False) -> jnp.ndarray:
-    """dense_out: (B, D); sparse_embs: (B, F, D) with same D.
+    """dense_out: (B, D); embs: (B, F, D) with same D.
 
     Returns (B, D + F'*(F'+offset)//2) where F' = F+1 (dense row included).
     """
     b, d = dense_out.shape
-    t = jnp.concatenate([dense_out[:, None, :], sparse_embs], axis=1)  # (B,F+1,D)
+    t = jnp.concatenate([dense_out[:, None, :], embs], axis=1)  # (B,F+1,D)
     z = jnp.einsum("bfd,bgd->bfg", t, t)                               # (B,F+1,F+1)
     f = t.shape[1]
     i, j = jnp.tril_indices(f, k=0 if self_interaction else -1)

@@ -106,7 +106,7 @@ def _user_side(params: Dict, cfg: LSRConfig, batch: ROOBatch,
     gathers locally, explicit psum lookups when an SPMD ``plan`` row-shards
     the table (each costs one B_RO-sized psum — the RO-side collective ROO
     shrinks, §2.2 Fig. 3), and ``GatheredTable`` proxies transparently under
-    sparse-gradient training.
+    the train step's touched-rows gradient.
     """
     d = cfg.embed_dim
     dense = mlp_apply(params["dense_proj"], batch.ro_dense)          # (B_RO,d)
@@ -222,20 +222,6 @@ def lsr_logits_impression(params: Dict, cfg: LSRConfig, batch: ROOBatch) -> jnp.
     x = jnp.concatenate([user, item], axis=-1)
     x = dcnv2_apply(params["cross"], x)
     return mlp_apply(params["top_mlp"], x)
-
-
-def lsr_table_ids(cfg: LSRConfig, batch: ROOBatch) -> Dict[str, jnp.ndarray]:
-    """Every id the ROO forward looks up, per embedding table — the
-    declaration ``embeddings.sparse.make_sparse_value_and_grad`` gathers
-    (and dedups) before differentiating w.r.t. the touched rows only."""
-    ids = {
-        "item_emb": jnp.concatenate([batch.history_ids.reshape(-1),
-                                     batch.item_ids.reshape(-1)]),
-        "act_emb": batch.history_actions.reshape(-1),
-    }
-    if batch.ro_sparse is not None:
-        ids["user_cat_emb"] = batch.ro_sparse["user_ids"].values.reshape(-1)
-    return ids
 
 
 def lsr_loss(params: Dict, cfg: LSRConfig, batch: ROOBatch,

@@ -86,13 +86,6 @@ def score_candidates_roo(params: Dict, cfg: MINDConfig,
     return jnp.max(scores, axis=-1)                          # serving rule
 
 
-def mind_table_ids(cfg: MINDConfig, batch: ROOBatch) -> Dict:
-    """Per-table id declaration for sparse-gradient training."""
-    return {"item_emb": jnp.concatenate([
-        batch.history_ids[:, :cfg.hist_len].reshape(-1),
-        batch.item_ids.reshape(-1)])}
-
-
 def mind_loss(params: Dict, cfg: MINDConfig, batch: ROOBatch,
               temperature: float = 0.1) -> jnp.ndarray:
     """Sampled-softmax over in-batch items with label-aware attention."""

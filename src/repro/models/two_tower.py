@@ -95,18 +95,6 @@ def item_tower(params: Dict, cfg: TwoTowerConfig, item_ids: jnp.ndarray,
     return v / (jnp.linalg.norm(v, axis=-1, keepdims=True) + 1e-6)
 
 
-def two_tower_table_ids(cfg: TwoTowerConfig, batch: ROOBatch) -> Dict:
-    """Per-table id declaration for sparse-gradient training
-    (``embeddings.sparse.make_sparse_value_and_grad``)."""
-    ids = {"item_emb": jnp.concatenate([batch.history_ids.reshape(-1),
-                                        batch.item_ids.reshape(-1)])}
-    if cfg.user_tower_mode == "hstu":
-        ids["act_emb"] = batch.history_actions.reshape(-1)
-    if batch.ro_sparse is not None:
-        ids["user_cat_emb"] = batch.ro_sparse["user_ids"].values.reshape(-1)
-    return ids
-
-
 def retrieval_loss_roo(params: Dict, cfg: TwoTowerConfig, batch: ROOBatch,
                        temperature: float = 0.05) -> jnp.ndarray:
     """In-batch softmax over all B_NRO items; positives = clicked impressions.

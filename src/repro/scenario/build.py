@@ -23,31 +23,10 @@ import dataclasses
 import hashlib
 import json
 import os
-from typing import Any, Callable, List, NamedTuple, Optional
+from typing import List, Optional
 
+from repro.scenario.archs import ARCHS, ModelBundle, arch_of
 from repro.scenario.spec import ScenarioSpec, ScenarioValidationError
-# ServeAdapter moved to repro.serve.adapter in PR 9 (first-class serving
-# interface); re-exported here because PRs 2-8 imported it from this module
-from repro.serve.adapter import ServeAdapter  # noqa: F401 (re-export)
-
-# archs the recsys scenario surface covers (dry-run-only archs excluded)
-RECSYS_ARCHS = ("roo-lsr", "roo-esr", "roo-retrieval", "hstu-gr",
-                "dien", "mind", "bert4rec", "dlrm-mlperf")
-
-# archs whose losses route embedding lookups through a sharding plan —
-# the only ones that may train under --mesh / train.mesh
-PLAN_ARCHS = ("roo-lsr", "hstu-gr")
-
-
-class ModelBundle(NamedTuple):
-    """Everything a trainer/server needs for one arch, built from a spec."""
-    arch: str
-    cfg: Any
-    params: Any
-    loss_fn: Callable
-    vag_fn: Optional[Callable]               # sparse value_and_grad (or None)
-    metrics_fn: Optional[Callable]
-    serve: Optional[ServeAdapter]            # None: arch is not ROO-servable
 
 
 # ---------------------------------------------------------------------------
@@ -67,29 +46,17 @@ def build_stream_cfg(spec: ScenarioSpec):
                          spec.batcher.hist_len))
 
 
-def _dlrm_layout(spec: ScenarioSpec):
-    from repro.models.dlrm import dlrm_batcher_config, dlrm_sample
-    cfg = dlrm_config(spec)
-    return (lambda base: dlrm_batcher_config(cfg, base),
-            lambda s: dlrm_sample(s, cfg))
-
-
-# archs whose model reads the joined samples in a field layout of its own:
-# spec -> (batcher config from the spec's sizes, sample mapping)
-FIELD_LAYOUTS = {"dlrm-mlperf": _dlrm_layout}
-
-
 def build_batcher_cfg(spec: ScenarioSpec, n_shards: int = 1):
     from repro.data.batcher import BatcherConfig
     cfg = BatcherConfig(b_ro=spec.batcher.b_ro, b_nro=spec.batcher.b_nro,
                         hist_len=spec.batcher.hist_len, n_shards=n_shards)
-    layout = FIELD_LAYOUTS.get(spec.model.arch)
+    layout = arch_of(spec).layout
     return layout(spec)[0](cfg) if layout else cfg
 
 
 def model_samples(spec: ScenarioSpec, samples: List) -> List:
     """Joined samples in the layout the spec's model reads."""
-    layout = FIELD_LAYOUTS.get(spec.model.arch)
+    layout = arch_of(spec).layout
     if layout is None:
         return samples
     to_model = layout(spec)[1]
@@ -147,167 +114,13 @@ def ckpt_meta(spec: ScenarioSpec) -> dict:
 
 
 # ---------------------------------------------------------------------------
-# Models (params + loss + sparse vag + metrics + serving halves)
+# Models (params + loss + metrics + serving halves)
 # ---------------------------------------------------------------------------
 
-def _ne_metrics(logits_fn):
-    from repro.train.metrics import make_ne_metrics
-    return make_ne_metrics(logits_fn)
-
-
-def build_model(spec: ScenarioSpec, rng, plan=None,
-                sparse: bool = False) -> ModelBundle:
-    """Params, loss and serving halves for ``spec.model`` — the spec-driven
-    successor of launch/train.py's per-arch dispatch table."""
-    import jax.numpy as jnp
-
-    from repro.configs import roo_models as rm
-    from repro.embeddings.sparse import make_sparse_value_and_grad
-
-    arch, m = spec.model.arch, spec.model
-    if arch not in RECSYS_ARCHS:
-        raise ScenarioValidationError(
-            f"scenario {spec.name!r}: model.arch {arch!r} is not a recsys "
-            f"scenario arch; expected one of {RECSYS_ARCHS}")
-
-    def sparse_vag(loss, table_ids_fn):
-        return (make_sparse_value_and_grad(loss, table_ids_fn)
-                if sparse else None)
-
-    if arch == "roo-lsr":
-        from repro.models.lsr import (lsr_init, lsr_logits_from_user,
-                                      lsr_logits_roo, lsr_loss, lsr_table_ids,
-                                      lsr_user_repr)
-        cfg = dataclasses.replace(rm.lsr_config(m.variant or "userarch_hstu"),
-                                  n_items=m.n_items)
-        loss = lambda p, b, r: lsr_loss(p, cfg, b, plan=plan)
-        return ModelBundle(
-            arch, cfg, lsr_init(rng, cfg), loss,
-            sparse_vag(loss, lambda b: lsr_table_ids(cfg, b)),
-            _ne_metrics(lambda p, b: (lsr_logits_roo(p, cfg, b, plan=plan)[:, 0],
-                                      b.labels[:, 0], b.impression_mask())),
-            ServeAdapter(
-                score=lambda p, b: lsr_logits_roo(p, cfg, b),
-                user_repr=lambda p, b: lsr_user_repr(p, cfg, b),
-                score_from_user=lambda p, b, u: lsr_logits_from_user(
-                    p, cfg, b, u)))
-    if arch == "roo-esr":
-        from repro.models.two_tower import (esr_logits_from_user,
-                                            esr_logits_roo, esr_loss_roo,
-                                            two_tower_init,
-                                            two_tower_table_ids, user_tower)
-        cfg = dataclasses.replace(rm.esr_config(), n_items=m.n_items)
-        loss = lambda p, b, r: esr_loss_roo(p, cfg, b)
-        return ModelBundle(
-            arch, cfg, two_tower_init(rng, cfg), loss,
-            sparse_vag(loss, lambda b: two_tower_table_ids(cfg, b)),
-            _ne_metrics(lambda p, b: (esr_logits_roo(p, cfg, b),
-                                      b.labels[:, 0], b.impression_mask())),
-            ServeAdapter(
-                score=lambda p, b: esr_logits_roo(p, cfg, b),
-                user_repr=lambda p, b: user_tower(p, cfg, b),
-                score_from_user=lambda p, b, u: esr_logits_from_user(
-                    p, cfg, b, u)))
-    if arch == "roo-retrieval":
-        from repro.models.two_tower import (item_tower, retrieval_loss_roo,
-                                            two_tower_init,
-                                            two_tower_table_ids, user_tower)
-        cfg = dataclasses.replace(rm.retrieval_config(), n_items=m.n_items)
-        loss = lambda p, b, r: retrieval_loss_roo(p, cfg, b)
-
-        def _fanout_scores(p, b, u):
-            v = item_tower(p, cfg, b.item_ids, b.nro_dense)
-            seg = jnp.minimum(b.segment_ids, b.b_ro - 1)
-            return jnp.sum(u[seg] * v, axis=-1)
-
-        return ModelBundle(
-            arch, cfg, two_tower_init(rng, cfg), loss,
-            sparse_vag(loss, lambda b: two_tower_table_ids(cfg, b)), None,
-            ServeAdapter(
-                score=lambda p, b: _fanout_scores(p, b,
-                                                  user_tower(p, cfg, b)),
-                user_repr=lambda p, b: user_tower(p, cfg, b),
-                score_from_user=_fanout_scores))
-    if arch == "hstu-gr":
-        from repro.models.gr import (gr_extend_user_state, gr_history_repr,
-                                     gr_init, gr_ranking_logits,
-                                     gr_ranking_logits_from_history,
-                                     gr_ranking_loss, gr_score_from_state,
-                                     gr_state_init, gr_table_ids)
-        cfg = dataclasses.replace(
-            rm.gr_config(hist_len=m.hist_len, m_targets=m.m_targets),
-            n_items=m.n_items)
-        loss = lambda p, b, r: gr_ranking_loss(p, cfg, b, plan=plan)
-        return ModelBundle(
-            arch, cfg, gr_init(rng, cfg), loss,
-            sparse_vag(loss, lambda b: gr_table_ids(cfg, b)),
-            _ne_metrics(lambda p, b: (
-                gr_ranking_logits(p, cfg, b, plan=plan)[:, 0],
-                b.labels[:, 0], b.impression_mask())),
-            ServeAdapter(
-                score=lambda p, b: gr_ranking_logits(p, cfg, b),
-                user_repr=lambda p, b: gr_history_repr(p, cfg, b),
-                score_from_user=lambda p, b, h:
-                    gr_ranking_logits_from_history(p, cfg, b, h),
-                init_user_state=lambda: gr_state_init(cfg),
-                extend_user_state=lambda p, b, s, *, n_new:
-                    gr_extend_user_state(p, cfg, b, s, n_new=n_new),
-                score_from_state=lambda p, b, s, *, n_new:
-                    gr_score_from_state(p, cfg, b, s, n_new=n_new),
-                state_hist_len=cfg.hist_len))
-    if arch == "mind":
-        from repro.models.mind import (MINDConfig, mind_init, mind_loss,
-                                       mind_table_ids, score_candidates_roo)
-        cfg = MINDConfig(n_items=m.n_items)
-        loss = lambda p, b, r: mind_loss(p, cfg, b)
-        return ModelBundle(
-            arch, cfg, mind_init(rng, cfg), loss,
-            sparse_vag(loss, lambda b: mind_table_ids(cfg, b)), None,
-            ServeAdapter(score=lambda p, b: score_candidates_roo(p, cfg, b)))
-    if arch == "bert4rec":
-        from repro.models.bert4rec import (BERT4RecConfig, bert4rec_init,
-                                           bert4rec_loss,
-                                           score_candidates_roo)
-        if sparse:
-            raise ScenarioValidationError(
-                "bert4rec's cloze head is a full softmax over item_emb — "
-                "dense by construction; drop train.sparse_emb")
-        cfg = BERT4RecConfig(n_items=m.n_items, seq_len=m.seq_len or 65)
-        return ModelBundle(
-            arch, cfg, bert4rec_init(rng, cfg),
-            lambda p, b, r: bert4rec_loss(p, cfg, b, r), None, None,
-            ServeAdapter(score=lambda p, b: score_candidates_roo(p, cfg, b)))
-    if arch == "dien":
-        from repro.models.din_dien import (DIENConfig, dien_init,
-                                           dien_logits_roo, dien_loss,
-                                           dien_table_ids)
-        cfg = DIENConfig(n_items=m.n_items, seq_len=m.seq_len or 64)
-        loss = lambda p, b, r: dien_loss(p, cfg, b)
-        return ModelBundle(
-            arch, cfg, dien_init(rng, cfg), loss,
-            sparse_vag(loss, lambda b: dien_table_ids(cfg, b)),
-            _ne_metrics(lambda p, b: (dien_logits_roo(p, cfg, b),
-                                      b.labels[:, 0], b.impression_mask())),
-            ServeAdapter(score=lambda p, b: dien_logits_roo(p, cfg, b)))
-    # dlrm-mlperf: the published widths, tables capped at model.n_items rows
-    from repro.models.dlrm import (dlrm_init, dlrm_logits_roo, dlrm_loss,
-                                   dlrm_table_ids)
-    cfg = dlrm_config(spec)
-    loss = lambda p, b, r: dlrm_loss(p, cfg, b, plan=plan)
-    return ModelBundle(
-        arch, cfg, dlrm_init(rng, cfg), loss,
-        sparse_vag(loss, lambda b: dlrm_table_ids(cfg, b)),
-        _ne_metrics(lambda p, b: (dlrm_logits_roo(p, cfg, b, plan=plan),
-                                  b.labels[:, 0], b.impression_mask())),
-        None)
-
-
-def dlrm_config(spec: ScenarioSpec):
-    """dlrm-mlperf at ``DLRMConfig``'s published widths, each table capped
-    at ``model.n_items`` rows."""
-    from repro.models.dlrm import DLRMConfig
-    return DLRMConfig(vocabs=tuple(min(v, spec.model.n_items)
-                                   for v in DLRMConfig.vocabs))
+def build_model(spec: ScenarioSpec, rng, plan=None) -> ModelBundle:
+    """Params, loss and serving halves for ``spec.model``, from its entry
+    in ``scenario/archs.ARCHS``."""
+    return arch_of(spec).build(spec, rng, plan)
 
 
 # ---------------------------------------------------------------------------
@@ -382,9 +195,10 @@ def _train_from_scenario(spec: ScenarioSpec, *, ckpt_dir, shard_dir,
         # only archs whose loss threads the plan into sharded lookups may
         # run under a mesh: sharding the state of a plan-blind loss would
         # silently re-gather every row-sharded table each step
-        if arch not in PLAN_ARCHS:
+        if not arch_of(spec).plan_routed:
+            routed = [a for a, e in ARCHS.items() if e.plan_routed]
             raise ScenarioValidationError(
-                f"train.mesh supports {', '.join(PLAN_ARCHS)} (their losses "
+                f"train.mesh supports {', '.join(routed)} (their losses "
                 f"route lookups through the sharding plan); {arch} would "
                 f"train slower sharded than replicated")
         from repro.distributed.sharding import plan_for_mesh
@@ -394,19 +208,8 @@ def _train_from_scenario(spec: ScenarioSpec, *, ckpt_dir, shard_dir,
         log.info("mesh",
                  axes=dict(zip(mesh.axis_names, mesh.devices.shape)),
                  devices=mesh.devices.size)
-    if tr.sparse_emb and plan is not None:
-        # the GatheredTable proxy gathers rows locally, bypassing the psum
-        # lookups a row-sharded table needs — pick one regime per run
-        raise ScenarioValidationError(
-            "train.sparse_emb and train.mesh are mutually exclusive: sparse "
-            "row grads assume locally-addressable tables (see "
-            "docs/EMBEDDINGS.md)")
 
-    bundle = build_model(spec, rng, plan=plan, sparse=tr.sparse_emb)
-    if tr.sparse_emb and bundle.vag_fn is None:
-        raise ScenarioValidationError(
-            f"{arch} has no table_ids declaration; train.sparse_emb "
-            f"unsupported")
+    bundle = build_model(spec, rng, plan=plan)
 
     n_data_shards = 1
     if plan is not None:
@@ -431,8 +234,7 @@ def _train_from_scenario(spec: ScenarioSpec, *, ckpt_dir, shard_dir,
                         keep_last=tr.keep_last, microbatches=tr.microbatches,
                         halt_after_skips=tr.halt_after_skips,
                         ckpt_meta=ckpt_meta(spec)),
-        lambda: bundle.params, plan=plan,
-        value_and_grad_fn=bundle.vag_fn, metrics_fn=bundle.metrics_fn)
+        lambda: bundle.params, plan=plan, metrics_fn=bundle.metrics_fn)
 
     if spec.data.source == "disk":
         state = _train_disk(spec, trainer, batcher_cfg, rng, plan,

@@ -31,7 +31,10 @@ apply_host_device_env()
 
 def smoke_one(spec, steps: int, trace: bool = False) -> dict:
     """Round-trip + short train + provenance + serve for one scenario."""
-    from repro.scenario.build import build_samples, train_from_scenario
+    import jax
+
+    from repro.scenario.build import (build_model, build_samples,
+                                      train_from_scenario)
     from repro.scenario.spec import ScenarioSpec
     from repro.serve.engine import ScoringEngine
 
@@ -63,7 +66,7 @@ def smoke_one(spec, steps: int, trace: bool = False) -> dict:
 
     # 4. serve the trained params through the same spec
     served = 0
-    if spec.model.arch != "dlrm-mlperf":
+    if build_model(run, jax.random.PRNGKey(0)).serve is not None:
         engine = ScoringEngine.from_scenario(run, params=state["params"])
         requests = build_samples(run.with_overrides(
             {"data.n_requests": 40}))[:8]

@@ -97,7 +97,6 @@ class TrainSpec:
     microbatches: int = 1
     lr_dense: float = 1e-3    # Adam on dense weights
     lr_emb: float = 0.05      # row-wise Adagrad on embedding tables
-    sparse_emb: bool = False  # touched rows from the model's table_ids
     halt_after_skips: int = 0
     mesh: str = ""            # "" = single device; else "DATAxMODEL" e.g. 2x4
 

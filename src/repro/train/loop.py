@@ -87,12 +87,13 @@ def make_train_step(loss_fn: Callable, opt: Optimizer,
     (``SparseRows``), and the optimizer reads and writes only those rows;
     every other leaf, and every leaf under an enabled ``plan``, gets its
     dense gradient. ``value_and_grad_fn(params, batch, rng) -> (loss,
-    grads)`` replaces that default (``jax.value_and_grad(loss_fn)`` for an
-    all-dense step). ``SparseRows`` leaves flow through accumulation and
-    into the optimizer: the dense part rides the scan carry as before, the
-    COO part is emitted per-microbatch and stacked by the scan (a COO sum IS
-    concatenation; the step's segment merge folds duplicates). The metrics
-    count the distinct rows touched (``touched_rows``).
+    grads)`` replaces that default; it is the hook through which tests pass
+    the all-dense oracle ``jax.value_and_grad(loss_fn)``. ``SparseRows``
+    leaves flow through accumulation and into the optimizer: the dense part
+    rides the scan carry as before, the COO part is emitted per-microbatch
+    and stacked by the scan (a COO sum IS concatenation; the step's segment
+    merge folds duplicates). The metrics count the distinct rows touched
+    (``touched_rows``).
 
     With an enabled ``plan`` (distributed/sharding.py) and the matching
     ``state_shardings`` pytree (distributed/spmd.py), the step runs SPMD:

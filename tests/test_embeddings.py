@@ -1,7 +1,8 @@
 """The unified embedding subsystem: dedup lookups, the GatheredTable proxy,
-SparseRows gradients, the sparse row-wise Adagrad apply, sparse-vs-dense
-training trajectory parity (LSR + DLRM), and the train step's default
-touched-rows gradient against an explicit dense one (DLRM + hstu-gr).
+SparseRows gradients, the sparse row-wise Adagrad apply, and the train
+step's default touched-rows gradient against an explicit dense one:
+training trajectory parity (LSR + DLRM) and one step at a time (DLRM,
+hstu-gr, LSR).
 
 Dedup and proxy lookups are pure index bookkeeping, so the contracts here
 are EXACT equality (assert_array_equal); the optimizer sparse apply is
@@ -18,7 +19,6 @@ from repro.data.jagged import JaggedTensor
 from repro.embeddings import collection as ec
 from repro.embeddings.sparse import (GatheredTable, SparseRows,
                                      gather_table,
-                                     make_sparse_value_and_grad,
                                      touched_rows_value_and_grad)
 from repro.train.optim import (adam, default_is_embedding, make_mixed,
                                rowwise_adagrad)
@@ -123,10 +123,11 @@ class TestSparseRows:
         np.testing.assert_allclose(np.asarray(m.to_dense()), dense)
 
     def test_flows_through_value_and_grad(self):
-        tbl = _rand_table(v=64, d=8, seed=3)
+        # 48 id slots a step: 1,024 rows keep them within TOUCHED_MAX_FILL
+        tbl = _rand_table(v=1024, d=8, seed=3)
         params = {"emb": tbl,
                   "w": jax.random.normal(jax.random.PRNGKey(4), (8,))}
-        ids = jax.random.randint(jax.random.PRNGKey(5), (12, 4), 0, 64)
+        ids = jax.random.randint(jax.random.PRNGKey(5), (12, 4), 0, 1024)
         lens = jnp.full((12,), 4, jnp.int32)
         batch = {"ids": ids, "lens": lens}
 
@@ -134,7 +135,7 @@ class TestSparseRows:
             e = ec.bag_lookup_dense(p["emb"], b["ids"], b["lens"], "mean")
             return jnp.sum((e @ p["w"]) ** 2)
 
-        vag = make_sparse_value_and_grad(loss, lambda b: {"emb": b["ids"]})
+        vag = touched_rows_value_and_grad(loss, _mixed().rows_mask)
         l_s, g_s = jax.jit(vag)(params, batch, jax.random.PRNGKey(0))
         l_d, g_d = jax.value_and_grad(loss)(params, batch, None)
         assert isinstance(g_s["emb"], SparseRows)
@@ -202,20 +203,21 @@ class TestSparseRowwiseAdagrad:
 
 class TestSparseGradAccum:
     def test_microbatch_scan_matches_dense(self):
-        """SparseRows grads ride the accumulation scan as stacked ys; the
-        resulting step must match the dense-grad step."""
+        """The default step's SparseRows grads ride the accumulation scan
+        as stacked ys; the resulting step must match the dense-grad step."""
         from repro.train.loop import make_train_step
         rng = jax.random.PRNGKey(0)
-        params = {"emb": _rand_table(v=64, d=8, seed=3) * 0.1,
+        # 48 id slots a microbatch: 1,024 rows keep them within
+        # TOUCHED_MAX_FILL, so the default takes the touched rows
+        params = {"emb": _rand_table(v=1024, d=8, seed=3) * 0.1,
                   "w": jax.random.normal(jax.random.PRNGKey(4), (8,))}
-        ids = jax.random.randint(jax.random.PRNGKey(5), (2, 12, 4), 0, 64)
+        ids = jax.random.randint(jax.random.PRNGKey(5), (2, 12, 4), 0, 1024)
         mb = {"ids": ids, "lens": jnp.full((2, 12), 4, jnp.int32)}
 
         def loss(p, b, r):
             e = ec.bag_lookup_dense(p["emb"], b["ids"], b["lens"], "mean")
             return jnp.sum((e @ p["w"]) ** 2)
 
-        vag = make_sparse_value_and_grad(loss, lambda b: {"emb": b["ids"]})
         opt = make_mixed(adam(1e-3), rowwise_adagrad(0.05),
                          default_is_embedding)
 
@@ -224,14 +226,16 @@ class TestSparseGradAccum:
                                    value_and_grad_fn=value_and_grad_fn)
             state = {"params": params, "opt": opt.init(params),
                      "step": jnp.zeros((), jnp.int32)}
-            losses = []
+            losses, touched = [], []
             for i in range(8):
                 state, m = step(state, mb, jax.random.fold_in(rng, i))
                 losses.append(float(m["loss"]))
-            return losses, state
+                touched.append(int(m["touched_rows"]))
+            return losses, touched, state
 
-        losses_d, state_d = run(jax.value_and_grad(loss))
-        losses_s, state_s = run(vag)
+        losses_d, _, state_d = run(jax.value_and_grad(loss))
+        losses_s, touched, state_s = run(None)
+        assert min(touched) > 0
         # The two paths add the microbatches' row contributions in different
         # orders (dense accumulator vs COO segment-sum): the first step's
         # table differs by 1 ulp at the table's scale, and 8 Adam/Adagrad
@@ -256,8 +260,9 @@ def _roo_batches(n_requests=60, n_items=512, b_ro=8, b_nro=32, hist=16):
 
 
 class TestSparseTrajectoryParity:
-    """Acceptance contract: sparse-grad training == dense-grad training,
-    loss trajectories within rtol 1e-5 over >= 50 steps, LSR and DLRM."""
+    """Acceptance contract: the default touched-rows training == dense-grad
+    training, loss trajectories within rtol 1e-5 over >= 50 steps, LSR and
+    DLRM."""
 
     def _run(self, loss, params, batches, vag, n_steps):
         from repro.train.loop import make_train_step
@@ -274,62 +279,32 @@ class TestSparseTrajectoryParity:
             losses.append(float(m["loss"]))
         return np.asarray(losses), state
 
-    def test_lsr_50_steps(self):
-        from repro.core.hstu import HSTUConfig
-        from repro.models.lsr import LSRConfig, lsr_init, lsr_loss, \
-            lsr_table_ids
-        cfg = LSRConfig(n_items=512, n_user_cats=64, n_item_cats=64,
-                        embed_dim=32, hist_len=16, mode="userarch_hstu",
-                        lce_n_out=4, lce_d_out=32, n_cross_layers=2,
-                        top_mlp=(64,),
-                        hstu=HSTUConfig(d_model=32, n_heads=2, d_qk=16,
-                                        d_v=16, n_layers=1, max_rel_pos=16))
-        params = lsr_init(jax.random.PRNGKey(0), cfg)
-        batches = _roo_batches()
-        loss = lambda p, b, r: lsr_loss(p, cfg, b)
-        vag = make_sparse_value_and_grad(loss,
-                                         lambda b: lsr_table_ids(cfg, b))
+    @pytest.mark.parametrize("model", ["lsr", "dlrm"])
+    def test_50_steps(self, model):
+        # the tables compared after the run, and the tables the default
+        # step must take on the touched rows
+        loss, params, batches, _ = {
+            "lsr": lambda: _tiny_lsr(n_batches=None),
+            "dlrm": lambda: _tiny_dlrm(vocabs=(512, 256, 64, 32),
+                                       n_batches=4)}[model]()
+        compared, sparse = {
+            "lsr": ("item_emb", {("item_emb",)}),
+            "dlrm": ("tables", {("tables", "t0"), ("tables", "t1")})}[model]
+        assert sparse <= _touched(loss, params, batches[0])
         losses_d, state_d = self._run(loss, params, batches,
                                       jax.value_and_grad(loss),
                                       N_TRAJECTORY_STEPS)
-        losses_s, state_s = self._run(loss, params, batches, vag,
+        losses_s, state_s = self._run(loss, params, batches, None,
                                       N_TRAJECTORY_STEPS)
         np.testing.assert_allclose(losses_s, losses_d, rtol=1e-5, atol=1e-7)
-        np.testing.assert_allclose(
-            np.asarray(state_s["params"]["item_emb"]),
-            np.asarray(state_d["params"]["item_emb"]), rtol=1e-4, atol=1e-6)
-
-    def test_dlrm_50_steps(self):
-        from repro.core.joiner import RequestLevelJoiner
-        from repro.data.batcher import BatcherConfig, ROOBatcher
-        from repro.data.events import EventSimulator, EventStreamConfig
-        from repro.models.dlrm import (DLRMConfig, dlrm_batcher_config,
-                                       dlrm_init, dlrm_loss, dlrm_sample,
-                                       dlrm_table_ids)
-        cfg = DLRMConfig(n_dense=4, embed_dim=16, bot_mlp=(4, 32, 16),
-                         top_mlp=(64, 32, 1), vocabs=(512, 256, 64, 32),
-                         n_ro_fields=2, multi_hot=2)
-        params = dlrm_init(jax.random.PRNGKey(0), cfg)
-        stream = EventStreamConfig(n_requests=60, n_items=512,
-                                   hist_init_max=12, seed=0)
-        samples = [dlrm_sample(s, cfg) for s in RequestLevelJoiner().join(
-            list(EventSimulator(stream).stream()))]
-        batches = list(ROOBatcher(dlrm_batcher_config(
-            cfg, BatcherConfig(b_ro=8, b_nro=32))).batches(samples))[:4]
-        loss = lambda p, b, r_: dlrm_loss(p, cfg, b)
-        vag = make_sparse_value_and_grad(
-            loss, lambda b: dlrm_table_ids(cfg, b))
-        losses_d, state_d = self._run(loss, params, batches,
-                                      jax.value_and_grad(loss),
-                                      N_TRAJECTORY_STEPS)
-        losses_s, state_s = self._run(loss, params, batches, vag,
-                                      N_TRAJECTORY_STEPS)
-        np.testing.assert_allclose(losses_s, losses_d, rtol=1e-5, atol=1e-7)
-        for name, tbl in state_d["params"]["tables"].items():
+        for (path, a), (_, b) in zip(
+                jax.tree_util.tree_flatten_with_path(
+                    state_s["params"][compared])[0],
+                jax.tree_util.tree_flatten_with_path(
+                    state_d["params"][compared])[0]):
             np.testing.assert_allclose(
-                np.asarray(state_s["params"]["tables"][name]),
-                np.asarray(tbl), rtol=1e-4, atol=1e-6,
-                err_msg=f"table {name} diverged")
+                np.asarray(a), np.asarray(b), rtol=1e-4, atol=1e-6,
+                err_msg=f"{compared}{jax.tree_util.keystr(path)} diverged")
 
 
 # ---------------------------------------------------------------------------
@@ -356,7 +331,7 @@ def _steps(loss, params, batches, vag=None, n=3, plan=None):
     return metrics, state
 
 
-def _tiny_dlrm(vocabs=(4096, 2048, 1024, 512, 32)):
+def _tiny_dlrm(vocabs=(4096, 2048, 1024, 512, 32), n_batches=3):
     """A tiny DLRM, its ROO batches, and the distinct valid ids each batch
     reads, per table path. A step reads 16 id slots of each user-side table
     (``t0``, ``t1``) and 64 of each item-side one: ``t2`` takes the touched
@@ -376,7 +351,7 @@ def _tiny_dlrm(vocabs=(4096, 2048, 1024, 512, 32)):
     samples = [dlrm_sample(s, cfg) for s in RequestLevelJoiner().join(
         list(EventSimulator(stream).stream()))]
     batches = list(ROOBatcher(dlrm_batcher_config(
-        cfg, BatcherConfig(b_ro=8, b_nro=32))).batches(samples))[:3]
+        cfg, BatcherConfig(b_ro=8, b_nro=32))).batches(samples))[:n_batches]
 
     def valid_ids(batch):
         ro_ids, ro_len, nro_ids, nro_len = (
@@ -415,6 +390,29 @@ def _tiny_gr():
             _roo_batches(n_items=cfg.n_items)[:3], valid_ids)
 
 
+def _tiny_lsr(n_batches=3):
+    """A tiny LSR, its ROO batches, and the distinct ids each batch's
+    lookups read from ``item_emb`` (history slots and targets, 160 id slots
+    a step; the other tables have 64 rows or fewer)."""
+    from repro.core.hstu import HSTUConfig
+    from repro.models.lsr import LSRConfig, lsr_init, lsr_loss
+    cfg = LSRConfig(n_items=4096, n_user_cats=64, n_item_cats=64,
+                    embed_dim=32, hist_len=16, mode="userarch_hstu",
+                    lce_n_out=4, lce_d_out=32, n_cross_layers=2,
+                    top_mlp=(64,),
+                    hstu=HSTUConfig(d_model=32, n_heads=2, d_qk=16,
+                                    d_v=16, n_layers=1, max_rel_pos=16))
+
+    def valid_ids(batch):
+        ids = np.concatenate([np.asarray(batch.history_ids).ravel(),
+                              np.asarray(batch.item_ids).ravel()])
+        return {("item_emb",): set(np.clip(ids, 0, cfg.n_items - 1).tolist())}
+
+    return (lambda p, b, r: lsr_loss(p, cfg, b),
+            lsr_init(jax.random.PRNGKey(0), cfg),
+            _roo_batches(n_items=cfg.n_items)[:n_batches], valid_ids)
+
+
 def _at(tree, path):
     for k in path:
         tree = tree[k]
@@ -431,7 +429,7 @@ def _touched(loss, params, batch):
             if isinstance(g, SparseRows)}
 
 
-MODELS = {"dlrm": _tiny_dlrm, "hstu-gr": _tiny_gr}
+MODELS = {"dlrm": _tiny_dlrm, "hstu-gr": _tiny_gr, "lsr": _tiny_lsr}
 
 
 class TestTouchedRowsDefault:

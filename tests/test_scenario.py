@@ -13,6 +13,7 @@ from repro.configs.registry import SCENARIO_ARCHS, all_scenarios, scenario
 from repro.kernels import dispatch
 from repro.scenario import (ScenarioSpec, ScenarioValidationError,
                             parse_set_args, resolve_knob)
+from repro.scenario.archs import ARCHS
 from repro.scenario.build import (build_stream_cfg, cursor_fingerprint,
                                   provenance_matches, shard_provenance)
 
@@ -319,6 +320,44 @@ class TestFlagSpecParity:
         assert all(m["scenario"] == spec.name for m in metas)
         assert all(m["scenario_hash"] == spec.content_hash() for m in metas)
         assert metas[0]["digests"] == metas[1]["digests"]
+
+
+# ---------------------------------------------------------------------------
+# the arch table: one entry per recsys arch
+# ---------------------------------------------------------------------------
+
+class TestArchTable:
+    @pytest.mark.parametrize("arch", SCENARIO_ARCHS)
+    def test_registered_scenario_validates(self, arch):
+        spec = scenario(arch)
+        assert spec.validate() is spec
+        assert spec.model.arch == arch
+
+    @pytest.mark.parametrize("arch", SCENARIO_ARCHS)
+    def test_builds_a_bundle(self, arch):
+        import jax
+        from repro.scenario.build import build_model
+        bundle = build_model(scenario(arch, {"model.n_items": 300}),
+                             jax.random.PRNGKey(0))
+        assert bundle.arch == arch
+        assert jax.tree.leaves(bundle.params)
+        assert (bundle.serve is None) == (arch == "dlrm-mlperf")
+
+    @pytest.mark.parametrize(
+        "arch", [a for a in SCENARIO_ARCHS if not ARCHS[a].plan_routed])
+    def test_mesh_refused_unless_plan_routed(self, arch):
+        from repro.scenario.build import train_from_scenario
+        spec = scenario(arch, {"train.mesh": "1x1"})
+        with pytest.raises(ScenarioValidationError, match="train.mesh"):
+            train_from_scenario(spec, prints=False)
+
+    def test_unknown_arch_names_the_table(self):
+        import jax
+        from repro.scenario.build import build_model
+        spec = scenario("roo-lsr", {"model.arch": "no-such-arch"})
+        with pytest.raises(ScenarioValidationError) as e:
+            build_model(spec, jax.random.PRNGKey(0))
+        assert all(a in str(e.value) for a in ARCHS)
 
 
 class TestEngineFromScenario:
